@@ -1,7 +1,9 @@
 """Beyond-paper ablation: gossip (DMF protocol) vs centralized all-reduce on
 a small LM — loss parity and consensus, quantified (EXPERIMENTS.md §Perf-B
-semantics note). Runs in a subprocess with 8 host devices so the harness
-itself keeps seeing the single real CPU device.
+semantics note). A loss-only ablation: it runs in a child process pinned
+to the CPU backend (``JAX_PLATFORMS=cpu``) with 8 host devices, so it never
+competes with the harness for an accelerator — a chip belongs to one
+process, and the harness has already touched JAX. A failed child raises.
 
 Writes ``BENCH_gossip_ablation.json`` (repo root + benchmarks/results
 mirror, the `common.save_json` BENCH_* convention). The subprocess hands
@@ -59,6 +61,7 @@ with open(sys.argv[1], "w") as f:
 def main(steps: int = 50):
     import os
     env = {**os.environ,
+           "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
            "PYTHONPATH": str(REPO / "src")}
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as tmp:
@@ -68,7 +71,9 @@ def main(steps: int = 50):
             [sys.executable, "-c", CODE, str(out_path)], capture_output=True,
             text=True, timeout=2400, env=env)
         if res.returncode != 0:
-            return {"error": res.stderr[-1500:]}
+            raise RuntimeError(
+                f"gossip ablation child exited {res.returncode}:\n"
+                f"{res.stderr[-1500:]}")
         data = json.loads(out_path.read_text())
     finally:
         out_path.unlink(missing_ok=True)

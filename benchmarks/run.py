@@ -73,6 +73,8 @@ def main() -> None:
 
         ensure_host_platform_devices(args.devices)
     only = parse_only(args.only)
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     if args.trace_out:
         from repro.obs import trace as trace_lib
@@ -306,17 +308,14 @@ def main() -> None:
         t0 = time.perf_counter()
         res = gossip_ablation.main()     # saves BENCH_gossip_ablation itself
         us = (time.perf_counter() - t0) * 1e6
-        if "error" in res:
-            print(f"gossip_ablation,{us:.0f},ERROR")
-        else:
-            print(
-                f"gossip_ablation,{us:.0f},"
-                f"allreduce={res['allreduce']['last']};"
-                f"gossip_d1={res['gossip_d1']['last']};"
-                f"gossip_d2={res['gossip_d2']['last']};"
-                f"gap={res['gossip_minus_allreduce_final_loss']};"
-                f"consensus_err={res['gossip_d1']['consensus_err']}"
-            )
+        print(
+            f"gossip_ablation,{us:.0f},"
+            f"allreduce={res['allreduce']['last']};"
+            f"gossip_d1={res['gossip_d1']['last']};"
+            f"gossip_d2={res['gossip_d2']['last']};"
+            f"gap={res['gossip_minus_allreduce_final_loss']};"
+            f"consensus_err={res['gossip_d1']['consensus_err']}"
+        )
 
     if want("perf_report"):
         from benchmarks import perf_report

@@ -72,11 +72,11 @@ def _loop_per_request(state, seen, users, k, n_timed):
 
 
 def _engine_path(state, index, train, users, k, microbatch, prune,
-                 interpret=True, n_shards=1):
+                 n_shards=1):
     eng = ServingEngine(
         state, index,
         ServingConfig(microbatch=microbatch, k=k, prune=prune,
-                      interpret=interpret, n_shards=n_shards),
+                      n_shards=n_shards),
         train=train,
     )
     eng.recommend(users[:microbatch])      # warm/compile

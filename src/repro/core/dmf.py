@@ -67,7 +67,6 @@ class DMFConfig:
     init_scale: float = 0.1
     seed: int = 0
     use_pallas: bool = False         # fused Pallas step kernel (ops.dmf_fused_step)
-    pallas_interpret: bool = True    # interpret=True on CPU; False on real TPU
     n_shards: int = 1                # learner-mesh width; >1 = SPMD epochs over
                                      # a row-sharded U/P/Q (sharding/dmf.py)
     dp_clip: float = float("inf")    # C — L2 bound per outgoing gradient message
@@ -203,7 +202,6 @@ def _step_deltas(U, P, Q, ui, vj, r, conf, cfg: DMFConfig, valid=None):
         du, gp, dq, loss = ops.dmf_fused_step(
             U[ui], P[ui, vj], Q[ui, vj], r, conf,
             theta=theta, alpha=cfg.alpha, beta=cfg.beta, gamma=cfg.gamma,
-            interpret=cfg.pallas_interpret,
         )
     else:
         gu, gp, gq, loss = _grads_and_loss(U[ui], P[ui, vj], Q[ui, vj], r, conf, cfg)
@@ -262,7 +260,7 @@ def _step_deltas_dp(U, P, Q, ui, vj, r, conf, cfg: DMFConfig, valid, noise):
         du, gp, dq, loss = ops.dmf_fused_step_dp(
             U[ui], P[ui, vj], Q[ui, vj], r, conf, z,
             theta=cfg.lr, alpha=cfg.alpha, beta=cfg.beta, gamma=cfg.gamma,
-            clip=cfg.dp_clip, interpret=cfg.pallas_interpret)
+            clip=cfg.dp_clip)
         if valid is not None:
             keep = valid.astype(du.dtype)[:, None]
             du, gp, dq = du * keep, gp * keep, dq * keep
@@ -1144,7 +1142,7 @@ def fit(
 
 def evaluate(
     state: DMFState, train: np.ndarray, test: np.ndarray, n_users: int, n_items: int,
-    ks=(5, 10), interpret: bool = True, n_shards: int = 1,
+    ks=(5, 10), n_shards: int = 1,
     chunk_users: int | None = None,
 ) -> dict[str, float]:
     """Ranking metrics via the streaming top-k kernel: the (I, J) score
@@ -1165,15 +1163,14 @@ def evaluate(
         from repro.sharding import dmf as sharded_dmf
         return sharded_dmf.evaluate_sharded(
             state, train, test, n_users, n_items, n_shards, ks=ks,
-            interpret=interpret, chunk_users=chunk_users)
+            chunk_users=chunk_users)
     kmax = max(ks)
     if chunk_users is None:
         train_mask = metrics_lib.masks_from_interactions(n_users, n_items, train)
         test_mask = metrics_lib.masks_from_interactions(n_users, n_items, test)
         V = state.P + state.Q                 # (I, J, K) per-learner factors
         _, idx = ops.recommend_topk_peruser(
-            state.U, V, jnp.asarray(train_mask), kmax, interpret=interpret
-        )
+            state.U, V, jnp.asarray(train_mask), kmax)
         return metrics_lib.evaluate_ranking_from_topk(
             np.asarray(idx), test_mask, ks)
     hits: dict[int, list[np.ndarray]] = {k: [] for k in ks}
@@ -1185,7 +1182,7 @@ def evaluate(
         ts = metrics_lib.masks_from_interactions_rows(s, e - s, n_items, test)
         V = state.P[s:e] + state.Q[s:e]       # only this chunk's item view
         _, idx = ops.recommend_topk_peruser(
-            state.U[s:e], V, jnp.asarray(tm), kmax, interpret=interpret)
+            state.U[s:e], V, jnp.asarray(tm), kmax)
         rec = np.asarray(idx)
         for k in ks:
             hits[k].append(metrics_lib.topk_hits(rec, ts, k))

@@ -1,3 +1,17 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the DMF hot paths (`dmf_update`, `dp_noise`,
+`topk_scores`, `serve_topk`, `gossip_mix`), their jit'd wrappers (`ops`)
+and pure-jnp oracles (`ref`)."""
+from __future__ import annotations
+
+import jax
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The one place the Pallas execution mode is chosen. ``None`` (every
+    default) compiles the kernel with Mosaic on a TPU backend and runs the
+    Pallas interpreter on any other backend; an explicit bool wins (the
+    compile tests pass ``False`` to lower for a described TPU from a CPU
+    process)."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)

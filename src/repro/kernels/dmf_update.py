@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _dmf_grads_kernel(u_ref, p_ref, q_ref, r_ref, c_ref,
                       gu_ref, gp_ref, gq_ref, *, alpha, beta, gamma):
@@ -35,7 +37,7 @@ def _dmf_grads_kernel(u_ref, p_ref, q_ref, r_ref, c_ref,
 
 
 def dmf_grads_kernel_call(u, p, q, r, conf, *, alpha, beta, gamma,
-                          block_b: int = 256, interpret: bool = True):
+                          block_b: int = 256, interpret: bool | None = None):
     """u/p/q: (B, K) f32; r/conf: (B,). K should be lane-aligned (wrapper
     pads). Returns (gu, gp, gq)."""
     B, K = u.shape
@@ -53,7 +55,7 @@ def dmf_grads_kernel_call(u, p, q, r, conf, *, alpha, beta, gamma,
         in_specs=[bspec_mat, bspec_mat, bspec_mat, bspec_col, bspec_col],
         out_specs=[bspec_mat, bspec_mat, bspec_mat],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(u, p, q, r2, c2)
     return gu, gp, gq
 
@@ -129,7 +131,7 @@ def _dmf_fused_step_dp_kernel(u_ref, p_ref, q_ref, r_ref, c_ref, z_ref,
 
 def dmf_fused_step_dp_kernel_call(u, p, q, r, conf, z, *, theta, alpha, beta,
                                   gamma, clip, block_b: int = 256,
-                                  interpret: bool = True):
+                                  interpret: bool | None = None):
     """DP variant of `dmf_fused_step_kernel_call`: extra input z (B, K) —
     the pre-scaled σC-Gaussian noise for this batch's messages (zero on
     padded rows/columns). Returns (du, g̃p, dq, loss) with g̃p the
@@ -157,13 +159,13 @@ def dmf_fused_step_dp_kernel_call(u, p, q, r, conf, z, *, theta, alpha, beta,
             jax.ShapeDtypeStruct((B, K), u.dtype),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(u, p, q, r2, c2, z)
     return du, gp, dq, loss
 
 
 def dmf_fused_step_kernel_call(u, p, q, r, conf, *, theta, alpha, beta, gamma,
-                               block_b: int = 256, interpret: bool = True):
+                               block_b: int = 256, interpret: bool | None = None):
     """u/p/q: (B, K) f32 (K lane-aligned by the wrapper); r/conf: (B,).
     Returns (du, gp, dq, loss): the -θ·grad deltas for u and q, the raw
     propagation gradient for p, and the summed batch loss (1, 1)."""
@@ -189,6 +191,6 @@ def dmf_fused_step_kernel_call(u, p, q, r, conf, *, theta, alpha, beta, gamma,
             jax.ShapeDtypeStruct((B, K), u.dtype),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(u, p, q, r2, c2)
     return du, gp, dq, loss

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 KMAX = 256                 # max factor dim the counter layout supports
 _STRIDE = 2 * KMAX         # uint32 counters per message row
 
@@ -77,9 +79,12 @@ def gauss_counter(seed, rid, n_cols: int):
             + col * np.uint32(2))
     h1 = _mix32(base ^ s_row)
     h2 = _mix32((base + np.uint32(1)) ^ (s_row * _GOLDEN))
-    # 24 high bits -> (0, 1] so log() is finite; [0, 1) for the angle
-    u1 = ((h1 >> np.uint32(8)) + np.uint32(1)).astype(jnp.float32) * (2.0**-24)
-    u2 = (h2 >> np.uint32(8)).astype(jnp.float32) * (2.0**-24)
+    # 24 high bits -> (0, 1] so log() is finite; [0, 1) for the angle. The
+    # values are < 2^25, so the int32 hop (Mosaic has no uint32 -> f32
+    # cast) and the f32 conversion are both exact.
+    u1 = (((h1 >> np.uint32(8)) + np.uint32(1)).astype(jnp.int32)
+          .astype(jnp.float32) * (2.0**-24))
+    u2 = (h2 >> np.uint32(8)).astype(jnp.int32).astype(jnp.float32) * (2.0**-24)
     return jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos((2.0 * jnp.pi) * u2)
 
 
@@ -109,7 +114,7 @@ def _dp_clip_noise_kernel(g_ref, rid_ref, seed_ref, out_ref,
 
 def dp_clip_noise_kernel_call(g, rid, seed, *, clip: float, noise_std: float,
                               n_real: int | None = None, block_b: int = 256,
-                              interpret: bool = True):
+                              interpret: bool | None = None):
     """g: (B, K) f32 messages (K lane-aligned by the wrapper); rid: (B,)
     int32 global row ids; seed: (1, 1) int32; ``n_real``: live columns
     (noise is only generated for those — the rest is K-padding the wrapper
@@ -134,5 +139,5 @@ def dp_clip_noise_kernel_call(g, rid, seed, *, clip: float, noise_std: float,
         in_specs=[bspec_mat, bspec_col, bspec_seed],
         out_specs=bspec_mat,
         out_shape=jax.ShapeDtypeStruct((B, K), g.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(g, rid2, seed)

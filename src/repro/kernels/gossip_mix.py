@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _mix_kernel(m_ref, x_ref, y_ref):
     k = pl.program_id(2)
@@ -32,7 +34,7 @@ def _mix_kernel(m_ref, x_ref, y_ref):
 
 
 def gossip_mix_kernel_call(M, X, *, block_m: int = 128, block_n: int = 128,
-                           block_k: int = 128, interpret: bool = True):
+                           block_k: int = 128, interpret: bool | None = None):
     """M: (I, I) f32, X: (I, F) f32 -> (I, F). Dims must be multiples of the
     MXU-aligned block sizes (the ops.py wrapper pads)."""
     I, I2 = M.shape
@@ -48,5 +50,5 @@ def gossip_mix_kernel_call(M, X, *, block_m: int = 128, block_n: int = 128,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((I, F), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(M, X)

@@ -1,9 +1,11 @@
 """jit'd public wrappers around the Pallas kernels (padding + dispatch).
 
-On this CPU container the kernels run with ``interpret=True`` (Pallas
-executes the kernel body in Python for correctness); on TPU pass
-``interpret=False`` for the compiled path. All wrappers pad to MXU/lane
-alignment (128) and slice back.
+Every wrapper takes ``interpret=None``, resolved by
+`repro.kernels.resolve_interpret` from the backend: compiled by Mosaic on a
+TPU, the Pallas interpreter elsewhere (the CPU test suite). An explicit
+``interpret=False`` lowers for a TPU from any process, which is how
+tests/test_tpu_compile.py compiles these kernels for a described v5e chip.
+All wrappers pad to MXU/lane alignment (128) and slice back.
 """
 from __future__ import annotations
 
@@ -29,7 +31,7 @@ def _pad_to(x, mult, axis):
 
 @functools.partial(jax.jit, static_argnames=("alpha", "beta", "gamma", "interpret"))
 def dmf_grads(u, p, q, r, conf, *, alpha: float, beta: float, gamma: float,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """Fused Eqs. 9-11. u/p/q: (B, K); r/conf: (B,)."""
     B, K = u.shape
     block_b = 256 if B % 256 == 0 else (B if B <= 256 else None)
@@ -52,7 +54,7 @@ def dmf_grads(u, p, q, r, conf, *, alpha: float, beta: float, gamma: float,
 @functools.partial(jax.jit, static_argnames=("theta", "alpha", "beta", "gamma",
                                              "interpret"))
 def dmf_fused_step(u, p, q, r, conf, *, theta: float, alpha: float, beta: float,
-                   gamma: float, interpret: bool = True):
+                   gamma: float, interpret: bool | None = None):
     """Fused Alg. 1 step: Eqs. 9-11 grads, lr-scaled u/q deltas, raw p
     message, batch loss — one kernel pass. u/p/q: (B, K); r/conf: (B,).
     Returns (du, gp, dq, loss_scalar)."""
@@ -77,7 +79,7 @@ def dmf_fused_step(u, p, q, r, conf, *, theta: float, alpha: float, beta: float,
                                              "clip", "interpret"))
 def dmf_fused_step_dp(u, p, q, r, conf, z, *, theta: float, alpha: float,
                       beta: float, gamma: float, clip: float,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """`dmf_fused_step` with the DP mechanism folded into the SAME kernel
     pass: the returned gp message is already clipped to ``clip`` and
     perturbed with ``z`` — the batch's pre-scaled σC noise block from the
@@ -103,7 +105,7 @@ def dmf_fused_step_dp(u, p, q, r, conf, z, *, theta: float, alpha: float,
 
 @functools.partial(jax.jit, static_argnames=("clip", "noise_std", "interpret"))
 def dp_clip_noise(g, rid, seed, *, clip: float, noise_std: float,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """Fused DP mechanism for gradient messages: per-row L2 clip to
     ``clip`` + additive N(0, noise_std²) counter-keyed Gaussian noise, one
     kernel pass (kernels/dp_noise.py). g: (B, K) f32; rid: (B,) int32
@@ -128,7 +130,7 @@ def dp_clip_noise(g, rid, seed, *, clip: float, noise_std: float,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def gossip_mix_op(M, X, *, interpret: bool = True):
+def gossip_mix_op(M, X, *, interpret: bool | None = None):
     """Y = M @ X with MXU tiling. M: (I, I); X: (I, F)."""
     I, F = X.shape
     Mp = _pad_to(_pad_to(M.astype(jnp.float32), LANE, 0), LANE, 1)
@@ -138,7 +140,7 @@ def gossip_mix_op(M, X, *, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def recommend_topk(U, V, train_mask, k: int, *, interpret: bool = True):
+def recommend_topk(U, V, train_mask, k: int, *, interpret: bool | None = None):
     """Masked top-k recommendation; never materializes (I, J) in HBM."""
     I, K = U.shape
     J = V.shape[0]
@@ -156,7 +158,7 @@ def recommend_topk(U, V, train_mask, k: int, *, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def serve_topk(U, V, cand, seen, k: int, *, interpret: bool = True):
+def serve_topk(U, V, cand, seen, k: int, *, interpret: bool | None = None):
     """Geo-pruned batched serving: per-request candidate gather + scores +
     running top-k fused (kernels/serve_topk.py). U: (R, K); V: (R, J, K)
     per-request item factors; cand: (R, Cw) int32 candidate item ids, -1
@@ -164,7 +166,7 @@ def serve_topk(U, V, cand, seen, k: int, *, interpret: bool = True):
     idx = global item ids, -1 in unfilled slots.
 
     *Compute* per request is O(Cw·K), not O(J·K) — the grid tiles the
-    candidate dim. Memory staging on this interpret-mode container is still
+    candidate dim. Memory staging is still
     O(J·K) per request (the user's full item slab is handed to the kernel
     as the gather source); the compiled-TPU design keeps V in HBM and DMAs
     only the candidate rows, making the traffic O(Cw·K) too. Padding: R to
@@ -188,7 +190,7 @@ def serve_topk(U, V, cand, seen, k: int, *, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def serve_topk_window(U, Vw, cand, seen_w, k: int, *, interpret: bool = True):
+def serve_topk_window(U, Vw, cand, seen_w, k: int, *, interpret: bool | None = None):
     """Tiled geo-pruned serving over pre-gathered candidate windows — the
     million-scale replacement for `serve_topk`'s per-request full item slab.
     U: (R, K); Vw: (R, Cw, K) the candidate windows' item factors (row r is
@@ -221,7 +223,7 @@ def serve_topk_window(U, Vw, cand, seen_w, k: int, *, interpret: bool = True):
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def serve_topk_window_quant(U, Vq, scale, cand, seen_w, k: int, *,
-                            interpret: bool = True):
+                            interpret: bool | None = None):
     """Quantized `serve_topk_window`: candidate windows as int8 codes with a
     per-request dequant scale (codes·scale ≈ v), or bf16 factors with
     scale = 1.0. Vq: (R, Cw, K) int8/bf16; scale: (R,) f32. Dequantization
@@ -248,7 +250,7 @@ def serve_topk_window_quant(U, Vq, scale, cand, seen_w, k: int, *,
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def recommend_topk_peruser(U, V, train_mask, k: int, *, interpret: bool = True):
+def recommend_topk_peruser(U, V, train_mask, k: int, *, interpret: bool | None = None):
     """DMF serving eval: per-user item factors V (I, J, K) — each learner
     scores only his own copy v^i = p^i + q^i. Streams item tiles through a
     running top-k; the (I, J) score matrix never materializes.

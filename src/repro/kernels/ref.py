@@ -1,8 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracles for every Pallas kernel, and the top-k contract the
+serving kernels are held to against them (`assert_topk_matches`)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def dmf_grads_ref(u, p, q, r, conf, alpha, beta, gamma):
@@ -27,11 +29,18 @@ def dmf_fused_step_ref(u, p, q, r, conf, theta, alpha, beta, gamma):
     return -theta * gu, gp, -theta * gq, loss
 
 
+def peruser_scores(U, V, train_mask):
+    """(I, J) per-user scores u_i . v^i_j, -inf where ``train_mask``. U:
+    (I, K), V: (I, J, K). K-major elementwise contraction (see
+    serve_topk_ref): it also keeps the oracle in float32 on a TPU, where an
+    einsum would take the MXU's reduced default precision."""
+    scores = jnp.sum(U[:, :, None] * jnp.transpose(V, (0, 2, 1)), axis=1)
+    return jnp.where(train_mask, -jnp.inf, scores)
+
+
 def topk_scores_peruser_ref(U, V, train_mask, k):
     """Per-user-factor serving oracle. U: (I, K), V: (I, J, K)."""
-    scores = jnp.einsum("ik,ijk->ij", U, V)
-    scores = jnp.where(train_mask, -jnp.inf, scores)
-    return jax.lax.top_k(scores, k)
+    return jax.lax.top_k(peruser_scores(U, V, train_mask), k)
 
 
 # single source of the dead-slot sentinel: the oracle must use the exact
@@ -68,6 +77,13 @@ def serve_topk_ref(U, V, cand, seen, k):
     return masked_topk_finalize(vals, idx)
 
 
+def window_scores(U, Vw, cand, seen_w):
+    """(R, Cw) candidate-window scores, NEG_INF on padded or seen slots."""
+    # K-major contraction (not einsum) — see serve_topk_ref
+    scores = jnp.sum(U[:, :, None] * jnp.transpose(Vw, (0, 2, 1)), axis=1)
+    return jnp.where((cand < 0) | (seen_w != 0), NEG_INF, scores)
+
+
 def serve_topk_window_ref(U, Vw, cand, seen_w, k):
     """Tiled-serving oracle over pre-gathered candidate windows: window
     scores, pad/seen masking, dense `lax.top_k` over window positions, then
@@ -80,12 +96,65 @@ def serve_topk_window_ref(U, Vw, cand, seen_w, k):
     in item id (index contract), so `top_k`'s lowest-position tie-break is
     the same lowest-item-id tie-break the streaming kernel implements.
     """
-    # K-major contraction (not einsum) — see serve_topk_ref
-    scores = jnp.sum(U[:, :, None] * jnp.transpose(Vw, (0, 2, 1)), axis=1)
-    scores = jnp.where((cand < 0) | (seen_w != 0), NEG_INF, scores)
-    vals, pos = jax.lax.top_k(scores, k)
+    vals, pos = jax.lax.top_k(window_scores(U, Vw, cand, seen_w), k)
     idx = jnp.take_along_axis(jnp.maximum(cand, 0), pos, axis=1)
     return masked_topk_finalize(vals, idx)
+
+
+# Serving contract: the streaming top-k kernels return the oracle's item ids
+# exactly and its scores to within MAX_ULP units in the last place of the
+# row's dot-product magnitude max_j sum_k |u_k v_jk|. The kernel and the
+# oracle contract K in different association orders (and XLA picks its own
+# per backend and version), so bitwise equality is not a property a
+# backend keeps. The rounding error of a K-term sum scales with its terms,
+# not with the (possibly cancelled) result: counted against each score
+# itself, a 1e-8 difference on a score near zero is tens of ULP.
+MAX_ULP = 4
+
+
+def assert_topk_matches(vals, idx, v_ref, i_ref, U, V, *, ref_scores=None,
+                        max_ulp: int = MAX_ULP) -> dict:
+    """Assert the serving contract of a kernel top-k ``(vals, idx)`` against
+    its oracle ``(v_ref, i_ref)``, all (n, k), for factors U (n, K) and
+    item rows V (n, m, K): every score within ``max_ulp`` ULP of the row's
+    magnitude, dead slots (NEG_INF, -1) where the oracle has them, and
+    item ids equal.
+
+    With ``ref_scores`` — the oracle's masked (n, J) score rows, indexed by
+    item id — an id may differ from the oracle's only at a near-tie: the
+    kernel's item must score within the same bound of the oracle's value
+    in that slot (two items that close may swap under another summation
+    order). Returns ``{"max_ulp", "id_mismatches"}``, the largest score
+    difference in row ULPs and the number of near-tie swaps."""
+    vals, idx, v_ref, i_ref = (np.asarray(a) for a in (vals, idx, v_ref,
+                                                        i_ref))
+    assert vals.shape == v_ref.shape and idx.shape == i_ref.shape, (
+        vals.shape, v_ref.shape, idx.shape, i_ref.shape)
+    mag = np.einsum("nk,nmk->nm", np.abs(np.asarray(U, np.float64)),
+                    np.abs(np.asarray(V, np.float64))).max(axis=1, initial=0)
+    unit = np.spacing(mag.astype(np.float32)).astype(np.float64)[:, None]
+    err = np.abs(vals.astype(np.float64) - v_ref.astype(np.float64)) / unit
+    assert err.max(initial=0.0) <= max_ulp, (
+        f"scores differ by {err.max():.3g} row ULP > {max_ulp}")
+    diff = idx != i_ref
+    if ref_scores is None or not diff.any():
+        np.testing.assert_array_equal(idx, i_ref)
+    else:
+        rows, slots = np.nonzero(diff)
+        got = idx[rows, slots]
+        assert (got >= 0).all() and (i_ref[rows, slots] >= 0).all(), (
+            "a filled slot differs from an empty one")
+        tie = np.abs(np.asarray(ref_scores, np.float64)[rows, got]
+                     - v_ref[rows, slots]) / unit[rows, 0]
+        assert tie.max() <= max_ulp, (
+            f"an id differs from the oracle's without a near-tie "
+            f"({tie.max():.3g} row ULP apart)")
+        for r in np.unique(rows):
+            ids = idx[r][idx[r] >= 0]
+            assert len(set(ids.tolist())) == len(ids), (
+                f"row {r}: an item is recommended twice")
+    return {"max_ulp": float(err.max(initial=0.0)),
+            "id_mismatches": int(diff.sum())}
 
 
 def dp_clip_noise_ref(g, rid, seed, clip, noise_std):

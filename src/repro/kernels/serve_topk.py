@@ -13,9 +13,8 @@ fuses, per (request, candidate) tile in one VMEM pass:
 Only O(Cw·K) *compute* is done per request instead of O(J·K): the grid's
 inner axis tiles the *candidate* dim, not the item dim — that is the
 geo-pruning (paper Fig. 2: check-ins concentrate in the home city). The
-gather source (the request's item slab) is still staged whole on this
-container — see `ops.serve_topk` for the HBM/DMA shape of the compiled
-design.
+gather source (the request's item slab) is still staged whole — see
+`ops.serve_topk` for the HBM/DMA shape of a DMA-fed design.
 
 The candidate gather is a per-row `take_along_axis` over the request's own
 item slab held in VMEM; the output index buffer carries global item ids
@@ -23,10 +22,9 @@ directly (no position→id remap pass afterwards). Unfilled slots (fewer
 unseen candidates than k, incl. all-seen users) stay at (NEG_INF, -1).
 
 Layout mirrors `topk_scores._topk_peruser_kernel`: V comes in as (R, K, J)
-so the lane dim is J and K sits on sublanes. On this CPU container the
-kernel runs interpret=True; on real TPU the per-request slab would be
-DMA'd from HBM per candidate window instead of staged whole — the compute
-and the top-k carry are identical.
+so the lane dim is J and K sits on sublanes. A compiled design would DMA
+the per-request slab from HBM per candidate window instead of staging it
+whole — the compute and the top-k carry are identical.
 
 Two kernel families live here:
 
@@ -45,10 +43,10 @@ Two kernel families live here:
   The quant variant takes int8 codes (+ a per-request f32 dequant scale) or
   bf16 factors and dequantizes in-VMEM before the identical score/merge.
 
-Tie contract (load-bearing for the exact-equality guarantee): candidate
-rows are in ascending item-id order and `_merge_tile_topk` only displaces
-on strictly-greater scores, so equal scores resolve to the lowest item id
-— the same tie-break as `jax.lax.top_k` on dense scores.
+Tie contract (load-bearing for the exact-id guarantee): `_merge_tile_topk`
+ranks by (score descending, item id ascending), and candidate rows are in
+ascending item-id order, so equal scores resolve to the lowest item id —
+the same tie-break as `jax.lax.top_k` on dense scores.
 """
 from __future__ import annotations
 
@@ -57,6 +55,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 from repro.kernels.topk_scores import NEG_INF, _merge_tile_topk
 
@@ -83,7 +83,7 @@ def _serve_topk_kernel(u_ref, v_ref, seen_ref, cand_ref, vals_ref, idx_ref, *, k
 
 
 def serve_topk_kernel_call(U, Vt, seen, cand, k: int, *, block_i: int = 8,
-                           block_j: int = 128, interpret: bool = True):
+                           block_j: int = 128, interpret: bool | None = None):
     """U: (R, K), Vt: (R, K, J) per-request item factors, seen: (R, J) int8,
     cand: (R, Cw) int32 global item ids (-1 = padded slot). Returns
     (vals (R, k), idx (R, k)) with idx holding global item ids, -1 where
@@ -114,7 +114,7 @@ def serve_topk_kernel_call(U, Vt, seen, cand, k: int, *, block_i: int = 8,
             jax.ShapeDtypeStruct((R, k), jnp.float32),
             jax.ShapeDtypeStruct((R, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(U, Vt, seen.astype(jnp.int8), cand)
     return vals, idx
 
@@ -140,7 +140,7 @@ def _serve_topk_window_kernel(u_ref, v_ref, seen_ref, cand_ref, vals_ref,
 
 def serve_topk_window_kernel_call(U, Vw, seen_w, cand, k: int, *,
                                   block_i: int = 8, block_j: int = 128,
-                                  interpret: bool = True):
+                                  interpret: bool | None = None):
     """Tiled serving over pre-gathered candidate windows. U: (R, K);
     Vw: (R, K, Cw) the requests' candidate-window item factors (K-major, the
     same layout the slab kernel produces internally from its gather);
@@ -176,7 +176,7 @@ def serve_topk_window_kernel_call(U, Vw, seen_w, cand, k: int, *,
             jax.ShapeDtypeStruct((R, k), jnp.float32),
             jax.ShapeDtypeStruct((R, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(U, Vw, seen_w.astype(jnp.int8), cand)
     return vals, idx
 
@@ -206,7 +206,7 @@ def _serve_topk_window_quant_kernel(u_ref, v_ref, scale_ref, seen_ref,
 
 def serve_topk_window_quant_kernel_call(U, Vq, scale, seen_w, cand, k: int, *,
                                         block_i: int = 8, block_j: int = 128,
-                                        interpret: bool = True):
+                                        interpret: bool | None = None):
     """Quantized tiled serving: `serve_topk_window_kernel_call` with the
     candidate windows carried as int8 codes (plus a per-request f32 dequant
     scale, (R, 1)) or bf16 factors (scale = 1.0). Dequantization happens
@@ -242,6 +242,6 @@ def serve_topk_window_quant_kernel_call(U, Vq, scale, seen_w, cand, k: int, *,
             jax.ShapeDtypeStruct((R, k), jnp.float32),
             jax.ShapeDtypeStruct((R, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(U, Vq, scale, seen_w.astype(jnp.int8), cand)
     return vals, idx

@@ -161,6 +161,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     _ensure_host_devices(args.n_shards)
+    from repro.launch import compile_cache
+    compile_cache.enable()
     if args.log_every > 0:
         import logging
         logging.basicConfig(level=logging.INFO,

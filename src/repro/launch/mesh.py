@@ -26,19 +26,15 @@ def ensure_host_platform_devices(n: int) -> None:
             f"{flags} --xla_force_host_platform_device_count={n}").strip()
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """Version-compat `shard_map`: jax >= 0.5 exposes ``jax.shard_map`` (with
-    ``check_vma``); 0.4.x only has ``jax.experimental.shard_map.shard_map``
-    (where the same knob is spelled ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+def auto_mesh(mesh):
+    """``mesh`` with every axis typed Auto. ``jax.make_mesh`` types axes
+    Explicit by default, and under Explicit axes every gather or
+    contraction over a sharded dim must state its output sharding; the LM
+    stack leaves that to GSPMD propagation, so its step builders re-type
+    the mesh they are given."""
+    from jax.sharding import AxisType, Mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

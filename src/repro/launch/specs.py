@@ -9,13 +9,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.launch.mesh import batch_axes
+from repro.launch.mesh import auto_mesh, batch_axes
 from repro.models import ssm as ssm_lib
 from repro.models.config import InputShape, ModelConfig
 
 
 def _sds(shape, dtype, mesh, spec):
-    return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(auto_mesh(mesh), spec))
 
 
 def _maybe(ax, size, mesh):

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import gossip as gossip_lib
-from repro.launch.mesh import batch_axes
+from repro.launch.mesh import auto_mesh, batch_axes
 from repro.models import transformer
 from repro.models.config import ModelConfig
 from repro.optim import Optimizer, apply_updates
@@ -48,6 +48,7 @@ def make_train_step(cfg: ModelConfig, mesh, opt: Optimizer, *, sync: str = "allr
     ``rules_overrides`` remaps logical axes (e.g. rules.DP_OVERRIDES for the
     pure-data-parallel §Perf layout).
     """
+    mesh = auto_mesh(mesh)
     if sync == "gossip":
         return _make_gossip_step(cfg, mesh, opt, gossip or gossip_lib.GossipConfig())
     return _make_allreduce_step(cfg, mesh, opt, rules_overrides)

@@ -229,9 +229,8 @@ def moe_ffn_sharded(params, x: jnp.ndarray, cfg: ModelConfig, dtype, mesh,
         return y.reshape(Bl, Sl, d), aux
 
     sub = {k: params[k] for k in routed_specs}
-    from repro.launch.mesh import shard_map
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )(sub, x)
     if cfg.n_shared_experts:

@@ -57,7 +57,6 @@ class ServingConfig:
     microbatch: int = 64     # R — fixed dispatch shape (requests padded to it)
     k: int = 10              # recommendations per request
     prune: bool = True       # geo-pruned candidate path vs dense full-J
-    interpret: bool = True   # Pallas interpret mode (CPU container default)
     n_shards: int = 1        # learner-mesh width: >1 serves row-sharded
                              # U/V/seen, one SPMD dispatch per microbatch
                              # of `microbatch` requests PER SHARD
@@ -110,9 +109,8 @@ class EngineStats:
             h.observe_many(getattr(self, nm))
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def _dispatch_pruned(U, V, seen, bucket_items, user_bucket, uids, *,
-                     k: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("k",))
+def _dispatch_pruned(U, V, seen, bucket_items, user_bucket, uids, *, k: int):
     """One geo-pruned microbatch: candidate-window gather + tiled serve
     kernel, a single compiled dispatch. Only the (R, cap, K) candidate
     windows are staged out of the HBM-resident factor buffer — never the
@@ -124,19 +122,19 @@ def _dispatch_pruned(U, V, seen, bucket_items, user_bucket, uids, *,
     safe = jnp.maximum(cand, 0)                   # pad-safe gather
     vw = V[uids[:, None], safe]                   # (R, cap, K) windows only
     sw = seen[uids[:, None], safe]                # (R, cap) window seen bits
-    return ops.serve_topk_window(u, vw, cand, sw, k, interpret=interpret)
+    return ops.serve_topk_window(u, vw, cand, sw, k)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def _dispatch_dense(U, V, seen, uids, *, k: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("k",))
+def _dispatch_dense(U, V, seen, uids, *, k: int):
     """Dense baseline microbatch: same gather, full-J streaming top-k."""
     return ops.recommend_topk_peruser(
-        U[uids], V[uids], seen[uids], k, interpret=interpret)
+        U[uids], V[uids], seen[uids], k)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret", "prune"))
+@functools.partial(jax.jit, static_argnames=("k", "prune"))
 def _dispatch_rows(U, P, Q, seen, bucket_items, user_bucket, uids, *,
-                   k: int, interpret: bool, prune: bool):
+                   k: int, prune: bool):
     """Shard-independent microbatch over the raw factor state: gathers the
     requested rows and forms their V = P + Q view on the fly (gather-then-add
     of the same rows is bitwise identical to gathering a precomputed V).
@@ -151,13 +149,13 @@ def _dispatch_rows(U, P, Q, seen, bucket_items, user_bucket, uids, *,
         safe = jnp.maximum(cand, 0)
         vw = P[uids[:, None], safe] + Q[uids[:, None], safe]   # (R, cap, K)
         sw = seen[uids[:, None], safe]
-        return ops.serve_topk_window(u, vw, cand, sw, k, interpret=interpret)
+        return ops.serve_topk_window(u, vw, cand, sw, k)
     v = P[uids] + Q[uids]
     s = seen[uids]
-    return ops.recommend_topk_peruser(u, v, s, k, interpret=interpret)
+    return ops.recommend_topk_peruser(u, v, s, k)
 
 
-def _make_sharded_dispatch(mesh, *, k: int, interpret: bool, prune: bool):
+def _make_sharded_dispatch(mesh, *, k: int, prune: bool):
     """SPMD serve dispatch over the ``learners`` mesh: every shard gathers
     its OWN users' (u_i, v^i, seen_i) rows and runs the same fused serve
     kernel (or the dense streaming kernel) on its local microbatch — one
@@ -166,7 +164,6 @@ def _make_sharded_dispatch(mesh, *, k: int, interpret: bool, prune: bool):
     replicated (items are global ids everywhere)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import shard_map
     from repro.sharding.dmf import AXIS
 
     def body(U, V, seen, user_bucket, bucket_items, uids):
@@ -177,12 +174,10 @@ def _make_sharded_dispatch(mesh, *, k: int, interpret: bool, prune: bool):
             safe = jnp.maximum(cand, 0)
             vw = V[u_l[:, None], safe]       # (R, cap, K) windows only
             sw = seen[u_l[:, None], safe]
-            return ops.serve_topk_window(u, vw, cand, sw, k,
-                                         interpret=interpret)
-        return ops.recommend_topk_peruser(
-            u, V[u_l], seen[u_l], k, interpret=interpret)
+            return ops.serve_topk_window(u, vw, cand, sw, k)
+        return ops.recommend_topk_peruser(u, V[u_l], seen[u_l], k)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(None, None), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS)),
@@ -261,7 +256,7 @@ class ServingEngine:
             self._seen_sh = jax.device_put(pad(self.seen, I_pad), sh)
             self._ub_sh = jax.device_put(pad(self._user_bucket, I_pad), sh)
             self._dispatch_sh = _make_sharded_dispatch(
-                self._mesh, k=cfg.k, interpret=cfg.interpret, prune=cfg.prune)
+                self._mesh, k=cfg.k, prune=cfg.prune)
         else:
             self.V = state.P + state.Q            # served per-learner view
         # persistent stream: successive ingest() calls must draw *fresh*
@@ -440,11 +435,10 @@ class ServingEngine:
                     vals, idx = _dispatch_pruned(
                         self.state.U, self.V, self.seen,
                         self._bucket_items, self._user_bucket, uids,
-                        k=self.cfg.k, interpret=self.cfg.interpret)
+                        k=self.cfg.k)
                 else:
                     vals, idx = _dispatch_dense(
-                        self.state.U, self.V, self.seen, uids,
-                        k=self.cfg.k, interpret=self.cfg.interpret)
+                        self.state.U, self.V, self.seen, uids, k=self.cfg.k)
                 jax.block_until_ready(idx)
             t1 = time.perf_counter()
             self.stats.dispatch_seconds.append(t1 - t0)
@@ -484,7 +478,7 @@ class ServingEngine:
             vals, idx = _dispatch_rows(
                 self.state.U, self.state.P, self.state.Q, self.seen,
                 self._bucket_items, self._user_bucket, jnp.asarray(buf),
-                k=k, interpret=self.cfg.interpret, prune=self.cfg.prune)
+                k=k, prune=self.cfg.prune)
             jax.block_until_ready(idx)
         dt = time.perf_counter() - t0
         self.stats.dispatch_seconds.append(dt)

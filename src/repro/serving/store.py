@@ -37,8 +37,11 @@ store (row-parallel, no cross-shard reads).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
@@ -46,11 +49,6 @@ from repro.serving.candidates import CandidateIndex
 from repro.serving.engine import EngineStats, ServingConfig
 
 _BF16_EPS = 2.0 ** -8     # round-to-nearest relative error bound of bfloat16
-
-
-def _bf16_dtype():
-    import jax.numpy as jnp
-    return jnp.bfloat16
 
 
 def synthetic_world(
@@ -114,8 +112,9 @@ class SyntheticFactors:
 
 @dataclasses.dataclass
 class TiledFactorStore:
-    """Per-user candidate-window factor slabs, HBM(host)-resident; see the
-    module docstring. ``seen`` is column-aligned to
+    """Per-user candidate-window factor slabs, built on the host; a
+    `TiledServingEngine` places the slab of its precision on the device.
+    See the module docstring. ``seen`` is column-aligned to
     ``index.bucket_items[index.user_bucket]``; ``cold``/``item_counts``
     carry the engine's graceful-degradation state (same semantics as
     `ServingEngine`: cold = user with no interactions anywhere)."""
@@ -230,7 +229,7 @@ class TiledFactorStore:
         self.q_codes, self.q_scale = codes, scale
 
     def quantize_bf16(self) -> None:
-        self.slab_bf16 = self.slab.astype(_bf16_dtype())
+        self.slab_bf16 = self.slab.astype(jnp.bfloat16)
 
     def int8_score_bound(self, users: np.ndarray) -> np.ndarray:
         """Per-request analytic |Δscore| bound: ||u||₁ · scale/2."""
@@ -270,6 +269,23 @@ class TiledFactorStore:
         return out
 
 
+@functools.partial(jax.jit, static_argnames=("k",))
+def _dispatch_windows(U, slab, scale, seen, bucket_items, user_bucket, uids,
+                      *, k: int):
+    """One fixed-shape microbatch against the device-resident store: gather
+    the requests' (R, cap, K) windows out of the slab and run the tiled
+    serve kernel — the only per-request arrays ever formed are the windows
+    in flight. ``scale`` is the int8 per-user dequant scale, None for fp32
+    and bf16."""
+    cand = bucket_items[user_bucket[uids]]
+    u, vw, sw = U[uids], slab[uids], seen[uids]
+    if slab.dtype == jnp.float32:
+        return ops.serve_topk_window(u, vw, cand, sw, k)
+    sc = (scale[uids] if scale is not None
+          else jnp.ones(uids.shape, jnp.float32))
+    return ops.serve_topk_window_quant(u, vw, sc, cand, sw, k)
+
+
 class TiledServingEngine:
     """Microbatched serving straight off a `TiledFactorStore` — the
     million-scale sibling of `ServingEngine`, same `ServingConfig`, same
@@ -277,7 +293,9 @@ class TiledServingEngine:
     requests get the popularity slate, flagged). ``mode`` picks the factor
     precision: 'fp32' (bitwise identical to `ServingEngine.recommend` built
     on the same factors), 'int8' or 'bf16' (bounded score error, see the
-    module docstring)."""
+    module docstring). The engine places U, the seen bits, the index and
+    the slab of its precision on the device once; requests then gather
+    their windows there."""
 
     def __init__(self, store: TiledFactorStore,
                  cfg: ServingConfig = ServingConfig(), *, mode: str = "fp32"):
@@ -291,6 +309,15 @@ class TiledServingEngine:
         self.store = store
         self.cfg = cfg
         self.mode = mode
+        slab, scale = {"fp32": (store.slab, None),
+                       "int8": (store.q_codes, store.q_scale),
+                       "bf16": (store.slab_bf16, None)}[mode]
+        self._dev = dict(
+            U=jnp.asarray(store.U), slab=jnp.asarray(slab),
+            scale=None if scale is None else jnp.asarray(scale),
+            seen=jnp.asarray(store.seen),
+            bucket_items=jnp.asarray(store.index.bucket_items),
+            user_bucket=jnp.asarray(store.index.user_bucket))
         self.stats = EngineStats()
         self._bucket_empty = (store.index.bucket_items < 0).all(axis=1)
         # popularity fallback slate — same construction as
@@ -310,29 +337,13 @@ class TiledServingEngine:
                 | self._bucket_empty[self.store.index.user_bucket[safe]])
 
     def _dispatch(self, uids: np.ndarray):
-        """One fixed-shape microbatch over host-gathered windows: the only
-        arrays that ever leave the HBM-resident store are the (R, cap, K)
-        windows of the requests in flight."""
-        import jax
-
+        """One fixed-shape microbatch: ids go to the device, slates come
+        back."""
         from repro.obs import trace as trace_lib
-        st, k = self.store, self.cfg.k
         with trace_lib.span("tiled.dispatch", mode=self.mode):
-            cand = st.index.bucket_items[st.index.user_bucket[uids]]
-            u = st.U[uids]
-            sw = st.seen[uids]
-            if self.mode == "fp32":
-                vals, idx = ops.serve_topk_window(
-                    u, st.slab[uids], cand, sw, k,
-                    interpret=self.cfg.interpret)
-            elif self.mode == "int8":
-                vals, idx = ops.serve_topk_window_quant(
-                    u, st.q_codes[uids], st.q_scale[uids], cand, sw, k,
-                    interpret=self.cfg.interpret)
-            else:
-                vals, idx = ops.serve_topk_window_quant(
-                    u, st.slab_bf16[uids], np.ones(len(uids), np.float32),
-                    cand, sw, k, interpret=self.cfg.interpret)
+            vals, idx = _dispatch_windows(
+                **self._dev, uids=jnp.asarray(uids, jnp.int32),
+                k=self.cfg.k)
             jax.block_until_ready(idx)
         return np.asarray(vals), np.asarray(idx)
 

@@ -49,7 +49,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import dmf as dmf_lib
 from repro.core import graph as graph_lib
 from repro.core import metrics as metrics_lib
-from repro.launch.mesh import shard_map
 
 AXIS = "learners"
 
@@ -440,7 +439,7 @@ def _epoch_sharded(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, rid, dp_seed,
     out_specs = (P_(AXIS), P_(AXIS), P_(AXIS), P_(None, AXIS))
     if tele:
         out_specs += (P_(AXIS),)
-    return shard_map(
+    return jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P_(AXIS), P_(AXIS), P_(AXIS), P_(AXIS), P_(AXIS),
                   P_(None, AXIS), P_(None, AXIS), P_(None, AXIS),
@@ -588,7 +587,7 @@ def _epoch_sharded_churn(U, P, Q, pidx, pwgt, dpidx, dpwgt, ui, vj, r, conf,
     out_specs = (P_(AXIS), P_(AXIS), P_(AXIS), P_(None, AXIS), P_())
     if tele:
         out_specs += (P_(AXIS),)
-    return shard_map(
+    return jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P_(AXIS), P_(AXIS), P_(AXIS), P_(AXIS), P_(AXIS),
                   P_(None, AXIS), P_(None, AXIS),
@@ -792,7 +791,7 @@ def train_epoch_sharded(
 def evaluate_sharded(
     state: dmf_lib.DMFState, train: np.ndarray, test: np.ndarray,
     n_users: int, n_items: int, n_shards: int, ks=(5, 10),
-    interpret: bool = True, chunk_users: int | None = None,
+    chunk_users: int | None = None,
 ) -> dict[str, float]:
     """`dmf.evaluate` over the learner mesh: each shard streams its own
     users' (rows, J, K) factors through the per-user top-k kernel; results
@@ -814,10 +813,9 @@ def evaluate_sharded(
     st = unpad_state(state, n_users)
 
     def body(U_loc, V_loc, m_loc):
-        return ops.recommend_topk_peruser(
-            U_loc, V_loc, m_loc, kmax, interpret=interpret)
+        return ops.recommend_topk_peruser(U_loc, V_loc, m_loc, kmax)
 
-    dispatch = jax.jit(shard_map(
+    dispatch = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(AXIS)),
         out_specs=(P(AXIS), P(AXIS)),

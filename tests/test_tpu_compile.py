@@ -1,0 +1,101 @@
+"""Compile-only checks of the main-path Pallas kernels for a TPU v5e.
+
+Each case lowers one `kernels.ops` wrapper with ``interpret=False`` for a
+v5e chip that is described, not attached (`topologies.get_topology_desc`),
+at the widths the system runs: the training step at the default batch, and
+the top-k kernels at Foursquare Table 1 scale (6,524 users x 3,197 POIs,
+K=10) and at the serving engine's microbatch and candidate-window shapes.
+Mosaic then refuses what the interpreter accepts (lane gathers, scatters,
+unsupported casts and layouts) here, at no chip time. Nothing runs, so the
+cases say nothing about results or speed.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _step(u, p, q, r, c):
+    return ops.dmf_fused_step(u, p, q, r, c, theta=0.1, alpha=0.1, beta=0.1,
+                              gamma=0.01, interpret=False)
+
+
+def _step_dp(u, p, q, r, c, z):
+    return ops.dmf_fused_step_dp(u, p, q, r, c, z, theta=0.1, alpha=0.1,
+                                 beta=0.1, gamma=0.01, clip=1.0,
+                                 interpret=False)
+
+
+def _dp_noise(g, rid, seed):
+    return ops.dp_clip_noise(g, rid, seed, clip=1.0, noise_std=0.5,
+                             interpret=False)
+
+
+def _peruser(U, V, mask):
+    return ops.recommend_topk_peruser(U, V, mask, 10, interpret=False)
+
+
+def _window(k):
+    def f(U, Vw, cand, seen_w):
+        return ops.serve_topk_window(U, Vw, cand, seen_w, k, interpret=False)
+    return f
+
+
+def _window_quant(k):
+    def f(U, Vq, scale, cand, seen_w):
+        return ops.serve_topk_window_quant(U, Vq, scale, cand, seen_w, k,
+                                           interpret=False)
+    return f
+
+
+F32, I32, I8, BF16, BOOL = (jnp.float32, jnp.int32, jnp.int8, jnp.bfloat16,
+                            jnp.bool_)
+B, K = 256, 10                       # DMFConfig.batch_size, dim
+I, J = 6524, 3197                    # Foursquare, Table 1
+
+
+def _windows(R, K, k, dtype=None):
+    if dtype is None:
+        return _window(k), [((R, K), F32), ((R, 128, K), F32),
+                            ((R, 128), I32), ((R, 128), BOOL)]
+    return _window_quant(k), [((R, K), F32), ((R, 128, K), dtype), ((R,), F32),
+                              ((R, 128), I32), ((R, 128), BOOL)]
+
+
+CASES = {
+    "dmf_fused_step": (_step, [((B, K), F32)] * 3 + [((B,), F32)] * 2),
+    "dmf_fused_step_dp": (_step_dp, [((B, K), F32)] * 3 + [((B,), F32)] * 2
+                          + [((B, K), F32)]),
+    "dp_clip_noise": (_dp_noise, [((B, K), F32), ((B,), I32), ((), I32)]),
+    "recommend_topk_peruser": (_peruser, [((I, K), F32), ((I, J, K), F32),
+                                          ((I, J), BOOL)]),
+    "serve_topk_window_r64": _windows(64, K, 10),
+    "serve_topk_window_r128": _windows(128, K, 10),
+    "serve_topk_window_quant_int8": _windows(128, 8, 8, I8),
+    "serve_topk_window_quant_bf16": _windows(128, 8, 8, BF16),
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = CASES[name]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{name}: no Mosaic kernel in the compiled program")
