@@ -27,7 +27,8 @@ SECTIONS = (
 
 def _section(name):
     # marker event in the span trace (no-op while tracing is disabled);
-    # repro.obs.trace imports no jax, so this is safe pre-device-flag
+    # importing repro.obs.trace binds no XLA flag, so this is safe
+    # pre-device-flag
     from repro.obs import trace as trace_lib
     trace_lib.get_tracer().instant("bench.section", section=name)
     print(f"# --- {name} " + "-" * max(0, 60 - len(name)), flush=True)

@@ -50,6 +50,7 @@ import numpy as np
 
 from repro.core import graph as graph_lib
 from repro.core import metrics as metrics_lib
+from repro.obs import trace as trace_lib
 
 
 @dataclasses.dataclass(frozen=True)
@@ -319,16 +320,19 @@ def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
     default) traces none of it: the compiled program is unchanged.
     """
     theta = cfg.lr
-    if cfg.dp and cfg.mode != "ldmf":
-        if noise is None:
-            noise = _dp_noise_rows(rid, dp_seed, cfg, U.shape[-1])
-        du, gp, dq, loss = _step_deltas_dp(
-            U, P, Q, ui, vj, r, conf, cfg, valid, noise)
-    else:
-        du, gp, dq, loss = _step_deltas(U, P, Q, ui, vj, r, conf, cfg, valid)
-    U = U.at[ui].add(du)
-    if cfg.mode != "gdmf":
-        Q = Q.at[ui, vj].add(dq)
+    with jax.named_scope("dmf.gather_grads"):
+        if cfg.dp and cfg.mode != "ldmf":
+            if noise is None:
+                noise = _dp_noise_rows(rid, dp_seed, cfg, U.shape[-1])
+            du, gp, dq, loss = _step_deltas_dp(
+                U, P, Q, ui, vj, r, conf, cfg, valid, noise)
+        else:
+            du, gp, dq, loss = _step_deltas(U, P, Q, ui, vj, r, conf, cfg,
+                                            valid)
+    with jax.named_scope("dmf.local_update"):
+        U = U.at[ui].add(du)
+        if cfg.mode != "gdmf":
+            Q = Q.at[ui, vj].add(dq)
     if tele:
         z = jnp.zeros((), du.dtype)
         u_sq = jnp.sum(du * du)
@@ -350,7 +354,8 @@ def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
         if recv_gate is not None:
             wb = wb * recv_gate[nb]                # offline receivers get 0
         upd = wb[:, :, None] * gp[:, None, :]      # (B, S, K)
-        P = P.at[nb, vj[:, None]].add(-theta * upd)
+        with jax.named_scope("dmf.p_scatter"):
+            P = P.at[nb, vj[:, None]].add(-theta * upd)
         if tele:
             gp2 = jnp.sum(gp * gp, axis=-1)              # (B,)
             selfm_t = (nb == ui[:, None]).astype(wb.dtype)
@@ -369,7 +374,8 @@ def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
     w_self = jnp.sum(wb * selfm, axis=1)
     if recv_gate is not None:
         w_self = w_self * recv_gate[ui]
-    P = P.at[ui, vj].add(-theta * w_self[:, None] * gp)
+    with jax.named_scope("dmf.p_scatter"):
+        P = P.at[ui, vj].add(-theta * w_self[:, None] * gp)
     # sender boundary: corrupt the outgoing copy only
     gp_sent = gp
     if amul is not None:
@@ -397,7 +403,8 @@ def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
         upd = jnp.where((wmsg > 0)[:, :, None],
                         wmsg[:, :, None] * gp_eff[:, None, :], 0.0)
     if byz.aggregation == "sum":
-        P = P.at[nb, vj_out[:, None]].add(-theta * upd)
+        with jax.named_scope("dmf.p_scatter"):
+            P = P.at[nb, vj_out[:, None]].add(-theta * upd)
         scat = upd
     else:
         b_id, b_pos, b_recv, b_item = bkt
@@ -407,7 +414,8 @@ def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
         comb = byz_lib.robust_combine(
             vals, validity, b_id.reshape(-1), b_pos.reshape(-1),
             b_recv.shape[-1], byz_cap, byz)
-        P = P.at[b_recv, b_item].add(-theta * comb)
+        with jax.named_scope("dmf.p_scatter"):
+            P = P.at[b_recv, b_item].add(-theta * comb)
         scat = comb
     if tele:
         n_pre = jnp.sum((wmsg_pre > 0).astype(wb.dtype))   # attempted
@@ -848,7 +856,8 @@ def train_epoch(
         return sharded_dmf.train_epoch_sharded(
             state, prop, train, cfg, rng, accountant=accountant, tele=tele)
     nbr = _as_neighbor_table(prop)
-    ui, vj, r, conf = sample_epoch(train, cfg, rng)
+    with trace_lib.span("fit.sample"):
+        ui, vj, r, conf = sample_epoch(train, cfg, rng)
     B = cfg.batch_size
     nb = len(ui) // B
     n = nb * B
@@ -856,17 +865,16 @@ def train_epoch(
     _, dp_seed = epoch_dp_inputs(cfg, rng, n)
     if accountant is not None:
         accountant.observe_epoch(ui[:n].reshape(shape))
-    out = _epoch_scan(
-        state.U, state.P, state.Q, nbr.idx, nbr.wgt,
-        jnp.asarray(ui[:n].reshape(shape)),
-        jnp.asarray(vj[:n].reshape(shape)),
-        jnp.asarray(r[:n].reshape(shape)),
-        jnp.asarray(conf[:n].reshape(shape)),
-        jnp.asarray(dp_seed, jnp.int32),
-        cfg, tele=tele,
-    )
+    with trace_lib.span("fit.h2d"):
+        batches = [jnp.asarray(a[:n].reshape(shape)) for a in (ui, vj, r, conf)]
+    with trace_lib.span("fit.launch"):
+        out = _epoch_scan(
+            state.U, state.P, state.Q, nbr.idx, nbr.wgt, *batches,
+            jnp.asarray(dp_seed, jnp.int32), cfg, tele=tele,
+        )
     U, P, Q, losses = out[:4]
-    total = float(np.asarray(losses, dtype=np.float64).sum())
+    with trace_lib.span("fit.loss_sync"):
+        total = float(np.asarray(losses, dtype=np.float64).sum())
     l = total / max(n, 1)
     if tele:
         return DMFState(U, P, Q), l, np.asarray(out[4])
@@ -983,7 +991,6 @@ def fit(
     assert not (tele_on and dense_reference), (
         "telemetry rides the sparse/sharded epoch programs")
     rng = np.random.default_rng(cfg.seed if seed is None else seed)
-    state = init_state(cfg, rng)
     accountant = None
     if cfg.dp and cfg.dp_sigma > 0.0:   # ldmf: no releases, no ε claim
         from repro.privacy import GaussianAccountant
@@ -1033,15 +1040,18 @@ def fit(
         )
         assert cfg.n_shards == 1, "dense_reference is the single-device oracle"
         assert not cfg.dp, "dense_reference is the un-noised oracle path"
-        prop = jnp.asarray(M)
-        epoch_fn = train_epoch_dense
-    elif cfg.n_shards > 1:
-        from repro.sharding import dmf as sharded_dmf
-        prop = sharded_dmf.make_shard_plan(_as_neighbor_table(M), cfg)
-        epoch_fn = train_epoch
-    else:
-        prop = _as_neighbor_table(M)
-        epoch_fn = train_epoch
+    with trace_lib.span("fit.init"):
+        state = init_state(cfg, rng)     # rng's first draws: none above
+        if dense_reference:
+            prop = jnp.asarray(M)
+            epoch_fn = train_epoch_dense
+        elif cfg.n_shards > 1:
+            from repro.sharding import dmf as sharded_dmf
+            prop = sharded_dmf.make_shard_plan(_as_neighbor_table(M), cfg)
+            epoch_fn = train_epoch
+        else:
+            prop = _as_neighbor_table(M)
+            epoch_fn = train_epoch
     collector = None
     if tele_on:
         from repro.obs import telemetry as tele_lib
@@ -1051,7 +1061,6 @@ def fit(
     if log_every:
         import logging
         logger = logging.getLogger("repro.dmf")
-    from repro.obs import trace as trace_lib
     tr_losses, te_losses = [], []
     start = 0
     if resume_from is not None:

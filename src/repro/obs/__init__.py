@@ -6,9 +6,9 @@ Three pillars, all off by default and structurally zero-cost when off:
   histograms with labels, plus THE single `latency_percentiles`
   definition shared by serving, scheduling and the benches.
 * `obs.trace`     — nestable span tracing (context manager + decorator,
-  monotonic clock, thread-safe) exporting Chrome-trace/Perfetto JSON,
-  with an optional `jax.profiler.trace` bridge and device-memory
-  snapshots for the GPU pass.
+  monotonic clock, thread-safe) exporting Chrome-trace/Perfetto JSON;
+  every span is also a `jax.profiler.TraceAnnotation`, so a profiler
+  session shows it on the device ops' clock.
 * `obs.telemetry` — per-epoch training telemetry (loss, update norms,
   DP ε trajectory, churn online counts, DelayRing occupancy, Byzantine
   screening counts, messages per shard) assembled host-side from

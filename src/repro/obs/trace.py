@@ -1,17 +1,20 @@
-"""Nestable span tracing with Chrome-trace/Perfetto export.
+"""Nestable span tracing on the profiler's clock, with Chrome-trace/
+Perfetto export.
 
-A `Tracer` records wall-clock spans (monotonic `perf_counter_ns`,
-thread-safe, nesting tracked per thread) and exports them as the
-Chrome trace-event JSON that Perfetto / `chrome://tracing` load
-directly. The module-level tracer is DISABLED by default: `span()`
-then returns a shared null context manager — no allocation, no clock
-read — so instrumented hot paths cost nothing until someone calls
-`configure_tracing(True)` (the `--trace-out` CLI flag does).
+Every span is also a `jax.profiler.TraceAnnotation`: while a profiler
+session records (`jax.profiler.trace`, or a benchmark's traced window) it
+lands on the session's host plane, on the same clock as the device's ops,
+with its keyword arguments as the event's stats. The arguments are encoded
+only while a session records.
 
-For the GPU pass (ROADMAP item 5) two bridges ride along:
-`Tracer.jax_profiler` wraps `jax.profiler.trace` (XLA-level timeline
-alongside these host-side spans), and `device_memory_snapshot()` grabs
-per-device `memory_stats()` where the backend exposes them.
+A `Tracer` additionally records wall-clock spans (monotonic
+`perf_counter_ns`, thread-safe, nesting tracked per thread) and exports
+them as the Chrome trace-event JSON that Perfetto / `chrome://tracing`
+load directly. The module-level tracer is DISABLED by default: with no
+profiler session `span()` then returns a shared null context manager — no
+allocation, no clock read — so instrumented hot paths cost nothing until
+someone calls `configure_tracing(True)` (the `--trace-out` CLI flag does)
+or starts a profiler session.
 """
 from __future__ import annotations
 
@@ -21,6 +24,11 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+# True while a profiler session records; TraceMe's own static check.
+_profiling = TraceAnnotation.is_enabled
 
 
 class _NullContext:
@@ -35,6 +43,11 @@ class _NullContext:
 
 
 _NULL = _NullContext()
+
+
+def _annotation(name: str, args: dict):
+    """The profiler's host span, or the null context with no session."""
+    return TraceAnnotation(name, **args) if _profiling() else _NULL
 
 
 class _Span:
@@ -66,34 +79,36 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, **args):
         """Time a block. Nesting is tracked per thread: the exported
-        event carries its depth and parent span name in ``args``."""
-        if not self.enabled:
-            yield None
-            return
-        stack = self._stack()
-        parent = stack[-1].name if stack else None
-        sp = _Span(name, time.perf_counter_ns(), args, len(stack), parent)
-        stack.append(sp)
-        try:
-            yield sp
-        finally:
-            stack.pop()
-            t1 = time.perf_counter_ns()
-            ev_args = {"depth": sp.depth}
-            if sp.parent is not None:
-                ev_args["parent"] = sp.parent
-            ev_args.update(sp.args)
-            ev = {
-                "name": name,
-                "ph": "X",
-                "ts": (sp.t0_ns - self._t0_ns) / 1e3,    # µs
-                "dur": (t1 - sp.t0_ns) / 1e3,            # µs
-                "pid": os.getpid(),
-                "tid": threading.get_ident(),
-                "args": ev_args,
-            }
-            with self._lock:
-                self._events.append(ev)
+        event carries its depth and parent span name in ``args``. The
+        block is a profiler annotation too, enabled or not."""
+        with _annotation(name, args):
+            if not self.enabled:
+                yield None
+                return
+            stack = self._stack()
+            parent = stack[-1].name if stack else None
+            sp = _Span(name, time.perf_counter_ns(), args, len(stack), parent)
+            stack.append(sp)
+            try:
+                yield sp
+            finally:
+                stack.pop()
+                t1 = time.perf_counter_ns()
+                ev_args = {"depth": sp.depth}
+                if sp.parent is not None:
+                    ev_args["parent"] = sp.parent
+                ev_args.update(sp.args)
+                ev = {
+                    "name": name,
+                    "ph": "X",
+                    "ts": (sp.t0_ns - self._t0_ns) / 1e3,    # µs
+                    "dur": (t1 - sp.t0_ns) / 1e3,            # µs
+                    "pid": os.getpid(),
+                    "tid": threading.get_ident(),
+                    "args": ev_args,
+                }
+                with self._lock:
+                    self._events.append(ev)
 
     def traced(self, name: str | None = None):
         """Decorator form of `span` (span name defaults to the function's
@@ -152,40 +167,6 @@ class Tracer:
             for name, d in sorted(agg.items())
         }
 
-    # -- accelerator bridges ----------------------------------------------
-    @contextlib.contextmanager
-    def jax_profiler(self, logdir):
-        """Wrap a block in `jax.profiler.trace(logdir)` when the tracer
-        is enabled (no-op otherwise) — the XLA-level timeline for the GPU
-        pass, complementary to these host-side spans."""
-        if not self.enabled:
-            yield
-            return
-        import jax
-        with jax.profiler.trace(str(logdir)):
-            yield
-
-
-def device_memory_snapshot() -> list[dict]:
-    """Per-device `memory_stats()` where the backend exposes them (GPU/
-    TPU runtimes do; CPU returns an empty stats dict per device). Never
-    raises — observability must not take the job down."""
-    try:
-        import jax
-        out = []
-        for d in jax.local_devices():
-            stats = {}
-            try:
-                stats = d.memory_stats() or {}
-            except Exception:
-                pass
-            out.append({"device": str(d), "platform": d.platform,
-                        "memory_stats": {k: int(v) for k, v in stats.items()
-                                         if isinstance(v, (int, float))}})
-        return out
-    except Exception:
-        return []
-
 
 _GLOBAL = Tracer(enabled=False)
 
@@ -208,9 +189,12 @@ def configure_tracing(enabled: bool = True) -> Tracer:
 
 
 def span(name: str, **args):
-    """Span on the global tracer — returns a shared null context (no
-    allocation) while tracing is disabled, so call sites in hot loops
-    stay free."""
-    if not _GLOBAL.enabled:
-        return _NULL
-    return _GLOBAL.span(name, **args)
+    """Span on the global tracer and the profiler: a bare
+    `TraceAnnotation` while only a profiler session records, and a shared
+    null context (no allocation, no clock read) while neither does, so
+    call sites in hot loops stay free."""
+    if _GLOBAL.enabled:
+        return _GLOBAL.span(name, **args)
+    if _profiling():
+        return TraceAnnotation(name, **args)
+    return _NULL

@@ -143,13 +143,16 @@ def _dispatch_rows(U, P, Q, seen, bucket_items, user_bucket, uids, *,
     lockstep over the whole mesh. The pruned path gathers only the
     (R, cap, K) candidate windows straight out of P/Q (gather-then-add of
     the same elements is bitwise identical to windowing a precomputed V)."""
-    u = U[uids]
     if prune:
-        cand = bucket_items[user_bucket[uids]]
-        safe = jnp.maximum(cand, 0)
-        vw = P[uids[:, None], safe] + Q[uids[:, None], safe]   # (R, cap, K)
-        sw = seen[uids[:, None], safe]
-        return ops.serve_topk_window(u, vw, cand, sw, k)
+        with jax.named_scope("serve.window_gather"):
+            u = U[uids]
+            cand = bucket_items[user_bucket[uids]]
+            safe = jnp.maximum(cand, 0)
+            vw = P[uids[:, None], safe] + Q[uids[:, None], safe]  # (R, cap, K)
+            sw = seen[uids[:, None], safe]
+        with jax.named_scope("serve.topk"):
+            return ops.serve_topk_window(u, vw, cand, sw, k)
+    u = U[uids]
     v = P[uids] + Q[uids]
     s = seen[uids]
     return ops.recommend_topk_peruser(u, v, s, k)
@@ -468,29 +471,34 @@ class ServingEngine:
         if n == 0:
             out = (np.empty((0, k), np.float32), np.empty((0, k), np.int32))
             return out + ((np.empty(0, bool),) if return_flags else ()) + (0.0,)
-        flags = (self._fallback_mask(user_ids) if self.cfg.fallback
-                 else np.zeros(n, bool))
-        buf = np.zeros(R, np.int32)
-        buf[:n] = np.where(flags, 0, user_ids)
-        buf[n:] = buf[0]           # pad with a real user id (results dropped)
-        t0 = time.perf_counter()
         with trace_lib.span("engine.serve_microbatch", n_real=n):
-            vals, idx = _dispatch_rows(
-                self.state.U, self.state.P, self.state.Q, self.seen,
-                self._bucket_items, self._user_bucket, jnp.asarray(buf),
-                k=k, prune=self.cfg.prune)
-            jax.block_until_ready(idx)
-        dt = time.perf_counter() - t0
-        self.stats.dispatch_seconds.append(dt)
-        self.stats.request_seconds.extend([dt] * n)
-        self.stats.n_dispatches += 1
-        self.stats.n_requests += n
-        vals = np.array(np.asarray(vals)[:n])
-        idx = np.array(np.asarray(idx)[:n])
-        if flags.any():
-            vals[flags] = self._pop_vals
-            idx[flags] = self._pop_items
-            self.stats.n_fallbacks += int(flags.sum())
+            with trace_lib.span("serve.prepare"):
+                flags = (self._fallback_mask(user_ids) if self.cfg.fallback
+                         else np.zeros(n, bool))
+                buf = np.zeros(R, np.int32)
+                buf[:n] = np.where(flags, 0, user_ids)
+                buf[n:] = buf[0]   # pad with a real user id (results dropped)
+            t0 = time.perf_counter()
+            with trace_lib.span("serve.launch"):
+                vals, idx = _dispatch_rows(
+                    self.state.U, self.state.P, self.state.Q, self.seen,
+                    self._bucket_items, self._user_bucket, jnp.asarray(buf),
+                    k=k, prune=self.cfg.prune)
+            with trace_lib.span("serve.device_wait"):
+                jax.block_until_ready(idx)
+            dt = time.perf_counter() - t0
+            self.stats.dispatch_seconds.append(dt)
+            self.stats.request_seconds.extend([dt] * n)
+            self.stats.n_dispatches += 1
+            self.stats.n_requests += n
+            with trace_lib.span("serve.fetch"):
+                vals = np.array(np.asarray(vals)[:n])
+                idx = np.array(np.asarray(idx)[:n])
+            with trace_lib.span("serve.fallback"):
+                if flags.any():
+                    vals[flags] = self._pop_vals
+                    idx[flags] = self._pop_items
+                    self.stats.n_fallbacks += int(flags.sum())
         if return_flags:
             return vals, idx, flags, dt
         return vals, idx, dt

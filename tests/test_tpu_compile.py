@@ -9,18 +9,27 @@ Mosaic then refuses what the interpreter accepts (lane gathers, scatters,
 unsupported casts and layouts) here, at no chip time. Nothing runs, so the
 cases say nothing about results or speed.
 
+Two more cases compile the whole training epoch (`dmf._epoch_scan`) and
+the serve dispatch (`engine._dispatch_rows`) at Table 1 scale with and
+without their `jax.named_scope` names: the scopes are op metadata only, so
+the optimized programs must match once that metadata is stripped.
+
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import dmf
 from repro.kernels import ops
+from repro.serving import engine
 
 
 @pytest.fixture(scope="module")
@@ -99,3 +108,63 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), (
         f"{name}: no Mosaic kernel in the compiled program")
+
+
+def _epoch_scan(one_chip):
+    def sds(shape, dtype=F32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    cfg = dmf.DMFConfig(n_users=I, n_items=J, dim=K, beta=0.1, gamma=0.01,
+                        batch_size=B)
+    nb, S = 110, 21                   # scan steps and walk fan-out, Table 1
+    return dmf._epoch_scan.lower(
+        sds((I, K)), sds((I, J, K)), sds((I, J, K)), sds((I, S), I32),
+        sds((I, S)), sds((nb, B), I32), sds((nb, B), I32), sds((nb, B)),
+        sds((nb, B)), sds((), I32), cfg)
+
+
+def _dispatch_rows(one_chip):
+    def sds(shape, dtype=F32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return engine._dispatch_rows.lower(
+        sds((I, K)), sds((I, J, K)), sds((I, J, K)), sds((I, J), I8),
+        sds((117, 384), I32), sds((I,), I32), sds((64,), I32),
+        k=10, prune=True)
+
+
+SCOPED = {
+    "epoch_scan": (_epoch_scan,
+                   ("dmf.gather_grads", "dmf.local_update", "dmf.p_scatter")),
+    "dispatch_rows": (_dispatch_rows, ("serve.window_gather", "serve.topk")),
+}
+
+
+def _strip(hlo: str) -> str:
+    """Optimized HLO text without its source tables and op metadata."""
+    lines = hlo.splitlines()
+    body = next(i for i, ln in enumerate(lines)
+                if ln.startswith(("%", "ENTRY")))
+    return "\n".join(lines[:1] + [re.sub(r", metadata=\{[^{}]*\}", "", ln)
+                                  for ln in lines[body:]])
+
+
+@pytest.mark.parametrize("name", list(SCOPED))
+def test_named_scopes_leave_the_v5e_program_unchanged(one_chip, name,
+                                                       monkeypatch):
+    lower, scopes = SCOPED[name]
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        jax.clear_caches()
+        scoped = lower(one_chip).compile().as_text()
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda _: contextlib.nullcontext())
+        jax.clear_caches()
+        plain = lower(one_chip).compile().as_text()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+        jax.config.update("jax_enable_compilation_cache", cache)
+    for scope in scopes:
+        assert scope in scoped, scope
+        assert scope not in plain, scope
+    assert _strip(scoped) == _strip(plain)
