@@ -270,6 +270,22 @@ def _step_deltas_dp(U, P, Q, ui, vj, r, conf, cfg: DMFConfig, valid, noise):
     return du, _dp_message(gp, noise, cfg, valid), dq, loss
 
 
+def _p_scatter_add(P, recv, items, upd):
+    """``P[recv[b, s], items[b]] += upd[b, s]`` for every row b and slot s.
+
+    ``recv`` (B, S) receivers, ``items`` (B,), ``upd`` (B, S, K). One
+    scatter-add of the form ``P.at[(B,), (B,)].add((B, K))`` per receiver
+    slot, unrolled over the static S. XLA compiles that form in place on P
+    in its own layout. The single-scatter form ``P.at[recv, items[:, None]]``
+    is linearized over I·J instead, which makes the compiler copy all of P
+    into and out of a flattened buffer at every call. Repeated (receiver,
+    item) pairs still sum; only the order of the float additions differs.
+    """
+    for s in range(recv.shape[1]):
+        P = P.at[recv[:, s], items].add(upd[:, s])
+    return P
+
+
 def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
                                   cfg: DMFConfig, valid=None, rid=None,
                                   dp_seed=None, noise=None, recv_gate=None,
@@ -355,7 +371,7 @@ def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
             wb = wb * recv_gate[nb]                # offline receivers get 0
         upd = wb[:, :, None] * gp[:, None, :]      # (B, S, K)
         with jax.named_scope("dmf.p_scatter"):
-            P = P.at[nb, vj[:, None]].add(-theta * upd)
+            P = _p_scatter_add(P, nb, vj, -theta * upd)
         if tele:
             gp2 = jnp.sum(gp * gp, axis=-1)              # (B,)
             selfm_t = (nb == ui[:, None]).astype(wb.dtype)

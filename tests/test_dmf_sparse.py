@@ -5,6 +5,7 @@ fused Pallas step) must reproduce the seed per-batch dense-M loop —
 same losses, same factors — for every mode and for paper_literal
 weighting. See DESIGN.md §5 for the equivalence argument.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -58,6 +59,52 @@ def test_scan_sparse_epoch_matches_dense_reference(mode):
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(rd.state.Q), np.asarray(rs.state.Q),
                                atol=1e-5)
+
+
+def _scatter_case(repeats):
+    """(P, recv, items, upd) for a small (I, J, K): walk-weighted messages,
+    the last slot of every row a zero-weight self pad. With ``repeats``
+    the same (receiver, item) pair is hit twice within row 0, and again
+    across rows (rows 0 and 3 share item 2, rows 1 and 2 item 4); without,
+    every live pair is distinct."""
+    rng = np.random.default_rng(11)
+    I, J, K = 9, 6, 3
+    senders = np.asarray([0, 1, 2, 3], np.int32)
+    if repeats:
+        items = np.asarray([2, 4, 4, 2], np.int32)
+        recv = np.asarray([[0, 5, 5, 0], [1, 5, 7, 1], [2, 5, 7, 2],
+                           [3, 0, 5, 3]], np.int32)
+    else:
+        items = np.asarray([2, 4, 1, 5], np.int32)
+        recv = np.asarray([[0, 5, 6, 0], [1, 5, 7, 1], [2, 8, 4, 2],
+                           [3, 0, 8, 3]], np.int32)
+    assert (recv[:, -1] == senders).all()
+    wgt = rng.uniform(0.2, 1.0, recv.shape).astype(np.float32)
+    wgt[:, -1] = 0.0
+    gp = rng.normal(size=(len(senders), K)).astype(np.float32)
+    upd = -0.1 * wgt[:, :, None] * gp[:, None, :]
+    P = rng.normal(size=(I, J, K)).astype(np.float32)
+    return (jnp.asarray(P), jnp.asarray(recv), jnp.asarray(items),
+            jnp.asarray(upd))
+
+
+@pytest.mark.parametrize("repeats", [True, False], ids=["repeats", "distinct"])
+def test_p_scatter_add_matches_single_scatter(repeats):
+    P, recv, items, upd = _scatter_case(repeats)
+    got = np.asarray(jax.jit(dmf._p_scatter_add)(P, recv, items, upd))
+    want = np.asarray(jax.jit(
+        lambda P, n, v, u: P.at[n, v[:, None]].add(u))(P, recv, items, upd))
+    pairs = {(int(n), int(v)) for row, v in zip(np.asarray(recv),
+                                                 np.asarray(items))
+             for n in row}
+    touched = np.zeros(P.shape[:2], bool)
+    touched[tuple(np.asarray(sorted(pairs)).T)] = True
+    # rows and items nobody sends to, and the zero-weight pads, stay exact
+    np.testing.assert_array_equal(got[~touched], np.asarray(P)[~touched])
+    if repeats:
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+    else:
+        np.testing.assert_array_equal(got, want)
 
 
 def test_scan_sparse_epoch_matches_dense_paper_literal():
