@@ -42,7 +42,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -136,9 +136,10 @@ def init_state(cfg: DMFConfig, rng: np.random.Generator | None = None) -> DMFSta
 #   * `_batch_step` — dense reference (seed): propagates every gradient
 #     through the full (I, I) walk matrix, O(I·B·K) per batch. Kept as the
 #     equivalence oracle and for `fit(..., dense_reference=True)`.
-#   * `_sparse_batch_update` — production path: gathers each sender's
-#     compact neighbor row from a `graph.NeighborTable` and scatter-adds
-#     into P, O(B·S·K) per batch (S = max 1+|N^D|; see DESIGN.md §5).
+#   * `_sparse_batch_update_messages` — production path: gathers each
+#     sender's compact neighbor row from a `graph.NeighborTable` and
+#     scatter-adds into P, O(B·S·K) per batch (S = max 1+|N^D|; see
+#     DESIGN.md §5).
 # ---------------------------------------------------------------------------
 def _grads_and_loss(u, p, q, r, conf, cfg: DMFConfig):
     """Eqs. 9-11 gradients and batch loss for gathered (B, K) factors —
@@ -270,20 +271,74 @@ def _step_deltas_dp(U, P, Q, ui, vj, r, conf, cfg: DMFConfig, valid, noise):
     return du, _dp_message(gp, noise, cfg, valid), dq, loss
 
 
+_SCATTER_ROWS = 512   # rows per in-place P scatter (see `_p_scatter_add`)
+
+
 def _p_scatter_add(P, recv, items, upd):
     """``P[recv[b, s], items[b]] += upd[b, s]`` for every row b and slot s.
 
     ``recv`` (B, S) receivers, ``items`` (B,), ``upd`` (B, S, K). One
     scatter-add of the form ``P.at[(B,), (B,)].add((B, K))`` per receiver
-    slot, unrolled over the static S. XLA compiles that form in place on P
-    in its own layout. The single-scatter form ``P.at[recv, items[:, None]]``
-    is linearized over I·J instead, which makes the compiler copy all of P
-    into and out of a flattened buffer at every call. Repeated (receiver,
-    item) pairs still sum; only the order of the float additions differs.
+    slot, unrolled over the static S, and per block of at most
+    `_SCATTER_ROWS` rows. The TPU compiler keeps that form in place on P in
+    its own layout up to 1,023 rows. From 1,024 rows on, and for the
+    single-scatter form ``P.at[recv, items[:, None]]`` at any size, it
+    linearizes P over I·J instead, which copies all of P into and out of a
+    flattened buffer at every call. Repeated (receiver, item) pairs still
+    sum; only the order of the float additions differs.
     """
+    if recv.shape[0] > _SCATTER_ROWS:
+        for c in range(0, recv.shape[0], _SCATTER_ROWS):
+            rows = slice(c, c + _SCATTER_ROWS)
+            P = _p_scatter_add(P, recv[rows], items[rows], upd[rows])
+        return P
     for s in range(recv.shape[1]):
         P = P.at[recv[:, s], items].add(upd[:, s])
     return P
+
+
+def _screened(g, w, byz):
+    """Messages ``g`` (..., K) delivered with weights ``w`` (..., S): the
+    receiver-side weights and the (..., S, K) weighted contributions.
+
+    With screening on (``byz.screen``, robustness/byzantine.py) a message
+    that is non-finite or over the norm cap gets weight 0 and zero content.
+    0·NaN = NaN: on an unscreened defended channel a zero-weight slot
+    (straggler, offline receiver, padding) whose sender bombed must still
+    deliver exactly 0, so the weight gates via `where` there. Screened
+    content is finite, so the plain multiply is safe, and ±0 contributions
+    leave the scatter-add unchanged. ``byz`` None: the plain multiply."""
+    if byz is not None and byz.screen:
+        from repro.robustness import byzantine as byz_lib
+        ok = byz_lib.screen_ok(g, byz.norm_cap)
+        g = jnp.where(ok[..., None] > 0, g, 0.0)
+        w = w * ok[..., None]
+    upd = w[..., None] * g[..., None, :]
+    if byz is not None and not byz.screen:
+        upd = jnp.where((w > 0)[..., None], upd, 0.0)
+    return w, upd
+
+
+def _deliver_ring(P, first, ring, recv, wgt, is_self, recv_gate, byz, theta):
+    """Delivery of the delay ring's messages due this epoch into P, as the
+    epoch scan's first step (``first`` True; the other steps pass P on).
+    Inside the scan, not ahead of it: as an op before the scan, the TPU
+    compiler keeps one more whole-P buffer (1.34 GB of temp at Table 1).
+
+    ``ring`` is `EpochFaults.ring`; ``recv``/``wgt`` (L·n, S) are the
+    buffered senders' receiver rows and walk weights in P's row space and
+    ``is_self`` marks their own slot. Neighbor slots only (the straggler
+    applied its own line-11 update at release), gated by the receivers'
+    online mask NOW, and screened AT DELIVERY: a malicious message buffered
+    k epochs ago must not dodge the gate by arriving late (the ring buffers
+    SENT, i.e. corrupted, content)."""
+    def deliver(P):
+        g, _, vj, due_now = ring
+        w = (wgt * (1.0 - is_self.astype(wgt.dtype)) * recv_gate[recv]
+             * due_now[:, None])
+        _, upd = _screened(g.reshape(-1, g.shape[-1]), w, byz)
+        return _p_scatter_add(P, recv, vj, -theta * upd)
+    return jax.lax.cond(first, deliver, lambda P: P, P)
 
 
 def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
@@ -302,15 +357,15 @@ def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
     With DP on (``cfg.dp``), the propagated message is clipped+noised
     before the scatter — every receiver, the sender's own line-11 P update
     included, applies only the noised message. Returns the per-row sent
-    messages too (the observed outbox stream the audit harness attacks);
-    `_sparse_batch_update` drops them for the training callers.
+    messages too (the observed outbox stream the audit harness attacks and
+    the delay ring buffers); the other callers discard them.
 
     Fault gates (robustness/faults.py; both None on the fault-free paths):
     ``recv_gate`` (I,) zeroes scatter weights into offline receivers —
     messages to an absent learner are lost, its P rows bit-frozen.
     ``prop_now`` (B,) restricts a straggler row's scatter to the sender's
     own line-11 self slot: its neighbor deliveries come from the delay
-    ring k epochs later (`_epoch_scan_churn`). All-ones gates multiply
+    ring k epochs later (`_epoch_scan`). All-ones gates multiply
     weights by 1.0 — bit-exact with the ungated path.
 
     Byzantine path (robustness/byzantine.py; ``byz`` a `DefenseConfig`,
@@ -403,24 +458,10 @@ def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
     if recv_gate is not None:
         wmsg = wmsg * recv_gate[nb]
     wmsg_pre = wmsg   # pre-screen delivery weights (telemetry baseline)
-    gp_eff = gp_sent
-    if byz.screen:
-        ok = byz_lib.screen_ok(gp_sent, byz.norm_cap)   # (B,)
-        gp_eff = jnp.where(ok[:, None] > 0, gp_sent, 0.0)
-        wmsg = wmsg * ok[:, None]
-    # 0·NaN = NaN: a zero-weight slot (straggler / offline receiver / padded)
-    # whose sender bombed must deliver exactly 0, so the weight gates via
-    # `where`, not multiplication. With screening on, gp_eff is already
-    # zeroed wherever it was non-finite, so the plain multiply is safe —
-    # and ±0 contributions leave the scatter-add bitwise unchanged.
-    if byz.screen:
-        upd = wmsg[:, :, None] * gp_eff[:, None, :]
-    else:
-        upd = jnp.where((wmsg > 0)[:, :, None],
-                        wmsg[:, :, None] * gp_eff[:, None, :], 0.0)
+    wmsg, upd = _screened(gp_sent, wmsg, byz)
     if byz.aggregation == "sum":
         with jax.named_scope("dmf.p_scatter"):
-            P = P.at[nb, vj_out[:, None]].add(-theta * upd)
+            P = _p_scatter_add(P, nb, vj_out, -theta * upd)
         scat = upd
     else:
         b_id, b_pos, b_recv, b_item = bkt
@@ -444,20 +485,62 @@ def _sparse_batch_update_messages(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf,
     return U, P, Q, loss, gp_sent
 
 
-def _sparse_batch_update(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf, cfg: DMFConfig,
-                         valid=None, rid=None, dp_seed=None, noise=None,
-                         tele=False):
-    out = _sparse_batch_update_messages(
-        U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf, cfg, valid, rid, dp_seed,
-        noise, tele=tele)
-    if tele:
-        U, P, Q, loss, _, tvec = out
-        return U, P, Q, loss, tvec
-    U, P, Q, loss, _ = out
-    return U, P, Q, loss
+class EpochFaults(NamedTuple):
+    """One epoch's fault and defence inputs (robustness/), as one pytree.
+
+    The per-row gates are always there; ``ring``, ``attack`` and
+    ``buckets`` are None when off. Which members are None is static pytree
+    structure under `jit`, so an absent input traces nothing.
+
+    * ``valid`` (nb, B) sender-online row mask, ``prop_now`` (nb, B)
+      full-scatter-this-epoch mask, ``recv_gate`` (I,) receiver-online mask;
+    * ``ring`` — the delay ring's view: buffered released messages ``gp``
+      (L, n, K), their senders ``ui``, items ``vj`` and the due-this-epoch
+      float mask ``deliver``, each (L·n,);
+    * ``attack`` — ``amul``/``ashill`` (nb, B) corruption rows, ``vjm``
+      (nb, B) message item addressing, ``dirs`` (I, K) shill content;
+    * ``buckets`` — the `MessageGroups` arrays ``(bucket_id, pos, recv,
+      item)`` of a robust aggregation.
+
+    On the learner mesh (sharding/dmf.py) the per-row members are routed
+    like the batches (nb, D, Bs), ``recv_gate`` and ``dirs`` are padded to
+    the mesh's rows, and the buckets are in per-destination received order.
+    """
+    valid: jnp.ndarray
+    prop_now: jnp.ndarray
+    recv_gate: jnp.ndarray
+    ring: tuple | None = None
+    attack: tuple | None = None
+    buckets: tuple | None = None
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "tele"),
+def _fault_rows(faults: EpochFaults | None, take=lambda a: a):
+    """The per-minibatch fault rows a scan walks with its batches:
+    ``(prop_now, attack rows | None, buckets | None)``, each through
+    ``take`` (the mesh's shard body drops its destination axis)."""
+    if faults is None:
+        return None
+    atk = (None if faults.attack is None
+           else tuple(map(take, faults.attack[:3])))
+    bkt = None if faults.buckets is None else tuple(map(take, faults.buckets))
+    return take(faults.prop_now), atk, bkt
+
+
+def _step_faults(rows, faults: EpochFaults, byz, byz_cap: int) -> dict:
+    """Keyword fault and defence inputs of one minibatch step, from its
+    `_fault_rows` slice ``rows`` (None: the fault-free step)."""
+    if rows is None:
+        return {}
+    prop_now, atk, bkt = rows
+    kw = dict(prop_now=prop_now, recv_gate=faults.recv_gate, byz=byz,
+              bkt=bkt, byz_cap=byz_cap)
+    if atk is not None:
+        kw.update(amul=atk[0], ashill=atk[1], vjm=atk[2],
+                  dirs=faults.attack[3])
+    return kw
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "tele", "byz", "byz_cap"),
                    donate_argnums=(0, 1, 2))
 def _epoch_scan(
     U: jnp.ndarray,
@@ -468,10 +551,13 @@ def _epoch_scan(
     ui: jnp.ndarray,           # (n_batches, B)
     vj: jnp.ndarray,
     r: jnp.ndarray,
-    conf: jnp.ndarray,
+    conf: jnp.ndarray,         # offline senders' rows already zeroed
     dp_seed: jnp.ndarray,      # () int32 per-epoch mechanism seed (traced)
     cfg: DMFConfig,
     tele: bool = False,        # static: emit the summed TELE_W reductions
+    faults: EpochFaults | None = None,
+    byz=None,                  # robustness.byzantine.DefenseConfig | None
+    byz_cap: int = 0,          # static: robust-combine bucket capacity
 ):
     """A full epoch as one device-resident `lax.scan` over minibatches —
     one dispatch per epoch instead of a Python loop with a host sync
@@ -484,290 +570,58 @@ def _epoch_scan(
     generation would pay the log/cos dispatch cost n_batches times for the
     same bits (measured ~50% epoch overhead on CPU vs ~1 noise-gen ms
     amortized). With DP off (the default) `dp_seed` is a dead input XLA
-    prunes and the compiled epoch is the exact PR 1 program."""
+    prunes and the compiled epoch is exactly the un-noised one.
+
+    Faults (``faults``; None traces none of this): (1) delivery of the
+    delay ring's messages due now ahead of the first minibatch
+    (`_deliver_ring`); (2) the per-row gates threaded into every minibatch
+    step; (3) with a ring, the epoch's released message stream returned as
+    an (n, K) block after the losses, for the ring to buffer. Under the trivial schedule
+    every gate multiplies by 1.0 — bitwise identity — so the outputs are
+    exactly the fault-free program's. ``byz`` turns on the defended
+    exchange of `_sparse_batch_update_messages`."""
     nb, B = ui.shape
+    ring = None if faults is None else faults.ring
+    first = None
+    if ring is not None:
+        recv = nbr_idx[ring[1]]
+        deliver = functools.partial(
+            _deliver_ring, ring=ring, recv=recv, wgt=nbr_wgt[ring[1]],
+            is_self=recv == ring[1][:, None], recv_gate=faults.recv_gate,
+            byz=byz, theta=cfg.lr)
+        first = jnp.arange(nb) == 0
     from repro.privacy import mechanism
-    noise_on = cfg.dp and cfg.mode != "ldmf" and mechanism.noise_std(cfg) > 0
-    if noise_on:
+    Z = None
+    if cfg.dp and cfg.mode != "ldmf" and mechanism.noise_std(cfg) > 0:
         from repro.kernels.dp_noise import gauss_counter
         K = U.shape[-1]
         rid = jnp.arange(nb * B, dtype=jnp.int32).reshape(-1, 1)
         Z = (mechanism.noise_std(cfg)
              * gauss_counter(dp_seed, rid, K)).reshape(nb, B, K)
-        xs = (ui, vj, r, conf, Z)
-    else:
-        xs = (ui, vj, r, conf)
+    valid = None if faults is None else faults.valid
 
     def body(carry, batch):
         U, P, Q = carry
-        b_ui, b_vj, b_r, b_conf = batch[:4]
-        out = _sparse_batch_update(
-            U, P, Q, nbr_idx, nbr_wgt, b_ui, b_vj, b_r, b_conf, cfg,
-            noise=batch[4] if noise_on else None, tele=tele,
-        )
-        if tele:
-            U, P, Q, loss, tvec = out
-            return (U, P, Q), (loss, tvec)
-        U, P, Q, loss = out
-        return (U, P, Q), loss
-
-    (U, P, Q), ys = jax.lax.scan(body, (U, P, Q), xs)
-    if tele:
-        losses, tvecs = ys
-        return U, P, Q, losses, tvecs.sum(axis=0)
-    return U, P, Q, ys
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "use_ring", "byz", "use_attack",
-                                    "byz_cap", "tele"),
-                   donate_argnums=(0, 1, 2))
-def _epoch_scan_churn(
-    U: jnp.ndarray,
-    P: jnp.ndarray,
-    Q: jnp.ndarray,
-    nbr_idx: jnp.ndarray,      # (I, S)
-    nbr_wgt: jnp.ndarray,      # (I, S)
-    ui: jnp.ndarray,           # (n_batches, B)
-    vj: jnp.ndarray,
-    r: jnp.ndarray,
-    conf: jnp.ndarray,         # offline senders' rows already zeroed
-    valid: jnp.ndarray,        # (n_batches, B) sender-online row mask
-    prop_now: jnp.ndarray,     # (n_batches, B) full-scatter-this-epoch mask
-    recv_gate: jnp.ndarray,    # (I,) receiver-online mask this epoch
-    ring_gp: jnp.ndarray,      # (L, n, K) buffered released messages
-    ring_ui: jnp.ndarray,      # (L·n,) buffered senders (flattened)
-    ring_vj: jnp.ndarray,      # (L·n,) buffered item ids
-    ring_deliver: jnp.ndarray,  # (L·n,) float mask: due exactly this epoch
-    dp_seed: jnp.ndarray,      # () int32 per-epoch mechanism seed (traced)
-    amul: jnp.ndarray,         # (n_batches, B) attack multipliers (dead if !use_attack)
-    ashill: jnp.ndarray,       # (n_batches, B) shill-replacement mask
-    vjm: jnp.ndarray,          # (n_batches, B) message item addressing
-    dirs: jnp.ndarray,         # (I, K) premultiplied shill content
-    b_id: jnp.ndarray,         # (n_batches, B, S) bucket ids (dead if sum agg)
-    b_pos: jnp.ndarray,        # (n_batches, B, S) in-bucket positions
-    b_recv: jnp.ndarray,       # (n_batches, NBK) bucket receiver rows
-    b_item: jnp.ndarray,       # (n_batches, NBK) bucket item ids
-    cfg: DMFConfig,
-    use_ring: bool,
-    byz=None,                  # robustness.byzantine.DefenseConfig | None
-    use_attack: bool = False,
-    byz_cap: int = 0,
-    tele: bool = False,        # static: emit the summed TELE_W reductions
-):
-    """`_epoch_scan` under a fault schedule: same one-dispatch epoch, with
-    (1) start-of-epoch delivery of the delay ring's messages due now —
-    neighbor slots only (the straggler applied its own line-11 update at
-    release), gated by the receivers' online mask NOW; (2) per-row fault
-    gates threaded into every minibatch step; (3) the epoch's released
-    message stream collected for the ring (only when ``use_ring``).
-
-    Under the trivial schedule (all masks 1, ``use_ring=False``) every
-    fault op is a multiply-by-1.0 — bitwise identity — so the compiled
-    epoch produces exactly `_epoch_scan`'s outputs.
-
-    Byzantine args (``byz``/``use_attack``/``byz_cap`` static): with
-    ``byz=None`` every attack/defense input is statically dead and the
-    trace is unchanged. A ring message due now is screened AT DELIVERY —
-    a malicious message buffered k epochs ago must not dodge the gate by
-    arriving late (the ring buffers SENT, i.e. corrupted, content)."""
-    theta = cfg.lr
-    if use_ring:
-        gflat = ring_gp.reshape(-1, ring_gp.shape[-1])    # (L·n, K)
-        nbd = nbr_idx[ring_ui]                            # (L·n, S)
-        wbd = nbr_wgt[ring_ui]
-        selfm = (nbd == ring_ui[:, None]).astype(wbd.dtype)
-        wbd = (wbd * (1.0 - selfm) * recv_gate[nbd]
-               * ring_deliver[:, None])
-        if byz is not None:
-            from repro.robustness import byzantine as byz_lib
-            if byz.screen:
-                okd = byz_lib.screen_ok(gflat, byz.norm_cap)
-                gflat = jnp.where(okd[:, None] > 0, gflat, 0.0)
-                wbd = wbd * okd[:, None]
-                # screened gflat is finite: plain multiply, ±0-neutral
-                upd = wbd[:, :, None] * gflat[:, None, :]
-            else:
-                upd = jnp.where((wbd > 0)[:, :, None],
-                                wbd[:, :, None] * gflat[:, None, :], 0.0)
-        else:
-            upd = wbd[:, :, None] * gflat[:, None, :]
-        P = P.at[nbd, ring_vj[:, None]].add(-theta * upd)
-    nb, B = ui.shape
-    from repro.privacy import mechanism
-    noise_on = cfg.dp and cfg.mode != "ldmf" and mechanism.noise_std(cfg) > 0
-    xs = [ui, vj, r, conf, valid, prop_now]
-    if noise_on:
-        from repro.kernels.dp_noise import gauss_counter
-        K = U.shape[-1]
-        rid = jnp.arange(nb * B, dtype=jnp.int32).reshape(-1, 1)
-        Z = (mechanism.noise_std(cfg)
-             * gauss_counter(dp_seed, rid, K)).reshape(nb, B, K)
-        xs.append(Z)
-    if use_attack:
-        xs += [amul, ashill]
-    robust = byz is not None and byz.aggregation != "sum"
-    if byz is not None:
-        xs.append(vjm)
-    if robust:
-        xs += [b_id, b_pos, b_recv, b_item]
-
-    def body(carry, batch):
-        U, P, Q = carry
-        b_ui, b_vj, b_r, b_conf, b_val, b_prop = batch[:6]
-        i = 6
-        b_noise = None
-        if noise_on:
-            b_noise = batch[i]
-            i += 1
-        b_amul = b_ashill = b_vjm = bkt = None
-        if use_attack:
-            b_amul, b_ashill = batch[i], batch[i + 1]
-            i += 2
-        if byz is not None:
-            b_vjm = batch[i]
-            i += 1
-        if robust:
-            bkt = batch[i:i + 4]
+        b_ui, b_vj, b_r, b_conf, b_noise, b_val, rows, b_first = batch
+        if ring is not None:
+            P = deliver(P, b_first)
         out = _sparse_batch_update_messages(
             U, P, Q, nbr_idx, nbr_wgt, b_ui, b_vj, b_r, b_conf, cfg,
-            valid=b_val, noise=b_noise,
-            recv_gate=recv_gate, prop_now=b_prop,
-            byz=byz, amul=b_amul, ashill=b_ashill,
-            dirs=dirs if use_attack else None, vjm=b_vjm, bkt=bkt,
-            byz_cap=byz_cap, tele=tele,
+            valid=b_val, noise=b_noise, tele=tele,
+            **_step_faults(rows, faults, byz, byz_cap),
         )
-        if tele:
-            U, P, Q, loss, gp, tvec = out
-        else:
-            U, P, Q, loss, gp = out
-        y = [loss]
-        if use_ring:
-            y.append(gp)
-        if tele:
-            y.append(tvec)
-        return (U, P, Q), (tuple(y) if len(y) > 1 else y[0])
+        return out[:3], (out[3], None if ring is None else out[4],
+                         out[5] if tele else None)
 
-    (U, P, Q), ys = jax.lax.scan(body, (U, P, Q), tuple(xs))
-    tele_sum = None
+    (U, P, Q), (losses, gps, tvecs) = jax.lax.scan(
+        body, (U, P, Q),
+        (ui, vj, r, conf, Z, valid, _fault_rows(faults), first))
+    out = (U, P, Q, losses)
+    if ring is not None:
+        out += (gps.reshape(nb * B, -1),)
     if tele:
-        ys, tvecs = (ys[:-1], ys[-1])
-        tele_sum = tvecs.sum(axis=0)
-        ys = ys if use_ring else ys[0]
-    if use_ring:
-        losses, gps = ys
-        out = (U, P, Q, losses, gps)
-    else:
-        out = (U, P, Q, ys, None)
-    if tele:
-        return out + (tele_sum,)
+        out += (tvecs.sum(axis=0),)
     return out
-
-
-def train_epoch_churn(
-    state: DMFState,
-    prop,
-    train: np.ndarray,
-    cfg: DMFConfig,
-    rng: np.random.Generator,
-    t: int,
-    plan,                       # robustness.faults.ChurnPlan
-    ring,                       # robustness.faults.DelayRing | None
-    accountant=None,
-    attack=None,                # robustness.byzantine.AttackPlan | None
-    byz=None,                   # robustness.byzantine.DefenseConfig | None
-    tele: bool = False,         # append the epoch's TELE_W device stats
-) -> tuple[DMFState, float]:
-    """`train_epoch` under a compiled `ChurnPlan` for epoch ``t``: the SAME
-    sampled stream (same rng consumption, per-epoch DP seed included), with
-    offline senders' rows zeroed host-side (conf=0 + valid=0 ⇒ their U/Q
-    rows bit-frozen and they release nothing), receivers gated by this
-    epoch's online mask, stragglers' neighbor scatters deferred through
-    ``ring``, and the accountant observing only the REALIZED stream.
-    Reported loss normalizes by realized (online) rows. ``cfg.n_shards>1``
-    dispatches to the SPMD counterpart (sharding/dmf.py).
-
-    ``attack`` (a compiled `AttackPlan`) corrupts the epoch's outgoing
-    messages at the sender boundary; ``byz`` (a `DefenseConfig`) turns on
-    receiver-side screening / robust aggregation. Both None (the default)
-    leaves the compiled epoch untouched. The delay ring buffers the SENT
-    (post-corruption) stream under shill re-addressing (``vjm``)."""
-    if cfg.n_shards > 1:
-        from repro.sharding import dmf as sharded_dmf
-        return sharded_dmf.train_epoch_churn_sharded(
-            state, prop, train, cfg, rng, t, plan, ring,
-            accountant=accountant, attack=attack, byz=byz, tele=tele)
-    nbr = _as_neighbor_table(prop)
-    ui, vj, r, conf = sample_epoch(train, cfg, rng)
-    B = cfg.batch_size
-    nb = len(ui) // B
-    n = nb * B
-    shape = (nb, B)
-    ui2 = ui[:n].reshape(shape)
-    vj2 = vj[:n].reshape(shape)
-    _, dp_seed = epoch_dp_inputs(cfg, rng, n)
-    on, sender_on, prop_now, due = plan.epoch_row_masks(t, ui2)
-    conf2 = conf[:n].reshape(shape) * sender_on
-    if accountant is not None:
-        accountant.observe_epoch(ui2, valid=sender_on)
-    use_ring = ring is not None
-    if use_ring:
-        r_ui = ring.ui.reshape(-1)
-        r_vj = ring.vj.reshape(-1)
-        r_del = (ring.due.reshape(-1) == t).astype(np.float32)
-        ring_gp = ring.gp
-    else:  # statically-skipped dummies (dead jit inputs)
-        r_ui = np.zeros(1, np.int32)
-        r_vj = np.zeros(1, np.int32)
-        r_del = np.zeros(1, np.float32)
-        ring_gp = jnp.zeros((1, 1, state.U.shape[-1]), jnp.float32)
-    use_attack = attack is not None
-    if use_attack:
-        assert byz is not None   # fit() supplies DefenseConfig() (all-off)
-        amul, ashill, vjm = attack.epoch_row_attack(
-            t, ui2, vj2, sender_on=sender_on)
-        dirs = jnp.asarray(attack.dirs)
-    else:
-        amul = ashill = np.zeros(1, np.float32)
-        vjm = vj2
-        dirs = jnp.zeros((1, state.U.shape[-1]), jnp.float32)
-    robust = byz is not None and byz.aggregation != "sum"
-    if robust:
-        from repro.robustness import byzantine as byz_lib
-        groups = byz_lib.group_messages(
-            ui2, vjm, nbr.idx, nbr.wgt, cfg.n_items,
-            sender_gate=sender_on.astype(bool) & prop_now.astype(bool),
-            recv_on=on.astype(bool))
-        gb = (jnp.asarray(groups.bucket_id), jnp.asarray(groups.pos),
-              jnp.asarray(groups.recv), jnp.asarray(groups.item))
-        byz_cap = groups.cap
-    else:
-        z1 = np.zeros(1, np.int32)
-        gb = (z1, z1, z1, z1)
-        byz_cap = 0
-    out = _epoch_scan_churn(
-        state.U, state.P, state.Q, nbr.idx, nbr.wgt,
-        jnp.asarray(ui2), jnp.asarray(vj2),
-        jnp.asarray(r[:n].reshape(shape)), jnp.asarray(conf2),
-        jnp.asarray(sender_on.astype(np.float32)),
-        jnp.asarray(prop_now.astype(np.float32)),
-        jnp.asarray(on.astype(np.float32)),
-        ring_gp, jnp.asarray(r_ui), jnp.asarray(r_vj), jnp.asarray(r_del),
-        jnp.asarray(dp_seed, jnp.int32),
-        jnp.asarray(amul), jnp.asarray(ashill), jnp.asarray(vjm), dirs,
-        gb[0], gb[1], gb[2], gb[3],
-        cfg, use_ring, byz, use_attack, byz_cap, tele=tele,
-    )
-    U, P, Q, losses, gps = out[:5]
-    if use_ring:
-        ring.write(t, gps.reshape(n, -1), ui2,
-                   vjm if byz is not None else vj2, due)
-    total = float(np.asarray(losses, dtype=np.float64).sum())
-    realized = int(sender_on.sum())
-    l = total / max(realized, 1)
-    if tele:
-        return DMFState(U, P, Q), l, np.asarray(out[5])
-    return DMFState(U, P, Q), l
 
 
 def sample_with_negatives(
@@ -845,6 +699,58 @@ def epoch_dp_inputs(cfg: DMFConfig, rng: np.random.Generator, n: int):
     return rid, mechanism.epoch_noise_seed(rng, cfg)
 
 
+@dataclasses.dataclass
+class _EpochInputs:
+    """Host side of one epoch, shared by both placements: the sampled
+    (nb, B) stream and its per-epoch DP seed (the same rng consumption with
+    or without faults), and under a fault plan the epoch's row masks, the
+    delay ring's due-now view and the attack realized on this stream."""
+    ui: np.ndarray
+    vj: np.ndarray
+    r: np.ndarray
+    conf: np.ndarray             # offline senders' rows zeroed
+    dp_seed: int
+    masks: tuple | None = None   # ChurnPlan.epoch_row_masks: (on, sender_on,
+                                 # prop_now, due)
+    ring: tuple | None = None    # EpochFaults.ring
+    attack: tuple | None = None  # AttackPlan.epoch_row_attack: (amul,
+                                 # ashill, vjm)
+
+    def finish(self, t: int, losses, block, ring) -> float:
+        """Buffer the epoch's released (n, K) message ``block`` in ``ring``
+        under the SENT item addressing; return the epoch loss normalized by
+        the realized (online) rows."""
+        if ring is not None:
+            vj = self.vj if self.attack is None else self.attack[2]
+            ring.write(t, block, self.ui, vj, self.masks[3])
+        total = float(np.asarray(losses, dtype=np.float64).sum())
+        rows = self.ui.size if self.masks is None else int(self.masks[1].sum())
+        return total / max(rows, 1)
+
+
+def _epoch_inputs(train, cfg: DMFConfig, rng, accountant=None, t: int = 0,
+                  plan=None, ring=None, attack=None) -> _EpochInputs:
+    with trace_lib.span("fit.sample"):
+        ui, vj, r, conf = sample_epoch(train, cfg, rng)
+    B = cfg.batch_size
+    n = (len(ui) // B) * B
+    ui, vj, r, conf = (a[:n].reshape(-1, B) for a in (ui, vj, r, conf))
+    _, dp_seed = epoch_dp_inputs(cfg, rng, n)
+    ep = _EpochInputs(ui, vj, r, conf, dp_seed)
+    if plan is not None:
+        ep.masks = plan.epoch_row_masks(t, ui)
+        ep.conf = conf * ep.masks[1]
+    if accountant is not None:
+        accountant.observe_epoch(
+            ui, valid=None if plan is None else ep.masks[1])
+    if ring is not None:
+        ep.ring = (ring.gp, ring.ui.reshape(-1), ring.vj.reshape(-1),
+                   (ring.due.reshape(-1) == t).astype(np.float32))
+    if attack is not None:
+        ep.attack = attack.epoch_row_attack(t, ui, vj, sender_on=ep.masks[1])
+    return ep
+
+
 def train_epoch(
     state: DMFState,
     prop,                       # graph.NeighborTable, or dense (I, I) M
@@ -853,6 +759,12 @@ def train_epoch(
     rng: np.random.Generator,
     accountant=None,
     tele: bool = False,         # append the epoch's TELE_W device stats
+    *,
+    t: int = 0,
+    plan=None,                  # robustness.faults.ChurnPlan | None
+    ring=None,                  # robustness.faults.DelayRing | None
+    attack=None,                # robustness.byzantine.AttackPlan | None
+    byz=None,                   # robustness.byzantine.DefenseConfig | None
 ) -> tuple[DMFState, float]:
     """Sparse-neighborhood scan epoch: one jitted dispatch for the whole
     epoch, O(B·S·K) propagation per batch. Passing a dense M converts it
@@ -866,34 +778,61 @@ def train_epoch(
 
     ``accountant`` (a `privacy.GaussianAccountant`) observes the epoch's
     realized minibatch stream for per-learner ε(δ) tracking when DP is on.
-    """
+
+    Faults (robustness/): under a compiled `ChurnPlan` ``plan`` for epoch
+    ``t`` the SAME sampled stream runs with offline senders' rows zeroed
+    host-side (conf=0 + valid=0 ⇒ their U/Q rows bit-frozen and they
+    release nothing), receivers gated by this epoch's online mask,
+    stragglers' neighbor scatters deferred through the `DelayRing`
+    ``ring``, and the accountant observing only the REALIZED stream; the
+    loss normalizes by realized (online) rows. ``attack`` (a compiled
+    `AttackPlan`) corrupts the outgoing messages at the sender boundary;
+    ``byz`` (a `DefenseConfig`) turns on receiver-side screening / robust
+    aggregation. Both need ``plan`` (`faults.no_churn` is the trivial one).
+    The ring buffers the SENT (post-corruption) stream under shill
+    re-addressing."""
+    assert plan is not None or all(x is None for x in (ring, attack, byz)), (
+        "fault and defence inputs run under a ChurnPlan")
+    assert attack is None or byz is not None   # fit() supplies DefenseConfig()
     if cfg.n_shards > 1:
         from repro.sharding import dmf as sharded_dmf
         return sharded_dmf.train_epoch_sharded(
-            state, prop, train, cfg, rng, accountant=accountant, tele=tele)
+            state, prop, train, cfg, rng, accountant=accountant, tele=tele,
+            t=t, plan=plan, ring=ring, attack=attack, byz=byz)
     nbr = _as_neighbor_table(prop)
-    with trace_lib.span("fit.sample"):
-        ui, vj, r, conf = sample_epoch(train, cfg, rng)
-    B = cfg.batch_size
-    nb = len(ui) // B
-    n = nb * B
-    shape = (nb, B)
-    _, dp_seed = epoch_dp_inputs(cfg, rng, n)
-    if accountant is not None:
-        accountant.observe_epoch(ui[:n].reshape(shape))
+    ep = _epoch_inputs(train, cfg, rng, accountant, t, plan, ring, attack)
+    faults, byz_cap = None, 0
+    if plan is not None:
+        on, sender_on, prop_now, _ = ep.masks
+        vjm = ep.vj if attack is None else ep.attack[2]
+        bkt = None
+        if byz is not None and byz.aggregation != "sum":
+            from repro.robustness import byzantine as byz_lib
+            groups = byz_lib.group_messages(
+                ep.ui, vjm, nbr.idx, nbr.wgt, cfg.n_items,
+                sender_gate=sender_on & prop_now, recv_on=on)
+            bkt = (groups.bucket_id, groups.pos, groups.recv, groups.item)
+            byz_cap = groups.cap
+        faults = EpochFaults(
+            sender_on.astype(np.float32), prop_now.astype(np.float32),
+            on.astype(np.float32), ep.ring,
+            None if attack is None else ep.attack + (attack.dirs,), bkt)
     with trace_lib.span("fit.h2d"):
-        batches = [jnp.asarray(a[:n].reshape(shape)) for a in (ui, vj, r, conf)]
+        batches = [jnp.asarray(a) for a in (ep.ui, ep.vj, ep.r, ep.conf)]
+        faults = jax.tree.map(jnp.asarray, faults)
     with trace_lib.span("fit.launch"):
-        out = _epoch_scan(
-            state.U, state.P, state.Q, nbr.idx, nbr.wgt, *batches,
-            jnp.asarray(dp_seed, jnp.int32), cfg, tele=tele,
-        )
+        args = (state.U, state.P, state.Q, nbr.idx, nbr.wgt, *batches,
+                jnp.asarray(ep.dp_seed, jnp.int32), cfg)
+        if faults is None:
+            out = _epoch_scan(*args, tele=tele)
+        else:
+            out = _epoch_scan(*args, tele=tele, faults=faults, byz=byz,
+                              byz_cap=byz_cap)
     U, P, Q, losses = out[:4]
     with trace_lib.span("fit.loss_sync"):
-        total = float(np.asarray(losses, dtype=np.float64).sum())
-    l = total / max(n, 1)
+        l = ep.finish(t, losses, out[4] if ring is not None else None, ring)
     if tele:
-        return DMFState(U, P, Q), l, np.asarray(out[4])
+        return DMFState(U, P, Q), l, np.asarray(out[-1])
     return DMFState(U, P, Q), l
 
 
@@ -980,9 +919,9 @@ def fit(
     `AttackConfig` (compiled here) or pre-compiled `AttackPlan` injecting
     malicious outgoing messages; ``defense`` is a `DefenseConfig` turning
     on receiver-side screening and/or robust aggregation. Either one
-    routes epochs through the churn machinery (a trivial all-online plan
-    when ``churn`` is None); both None leaves every compiled program
-    bit-exact with the defenseless stack.
+    runs epochs under a fault plan (a trivial all-online plan when
+    ``churn`` is None); both None leaves every compiled program bit-exact
+    with the defenseless stack.
 
     Observability (obs/, DESIGN.md §14): ``telemetry=True`` (or a
     ``telemetry_out`` JSONL path) collects one event dict per epoch —
@@ -1045,8 +984,8 @@ def fit(
         from repro.robustness.byzantine import DefenseConfig
         byz = DefenseConfig()    # undefended channel, byz path on
     if (attack_plan is not None or byz is not None) and plan is None:
-        # the byzantine exchange runs on the churn epoch program — use the
-        # trivial all-online schedule (bit-exact gates), no delay ring
+        # the byzantine exchange runs under a fault plan — use the trivial
+        # all-online schedule (bit-exact gates), no delay ring
         from repro.robustness import faults
         assert not dense_reference, "byzantine runs the sparse/sharded paths"
         plan = faults.no_churn(cfg.n_users, epochs)
@@ -1060,14 +999,11 @@ def fit(
         state = init_state(cfg, rng)     # rng's first draws: none above
         if dense_reference:
             prop = jnp.asarray(M)
-            epoch_fn = train_epoch_dense
         elif cfg.n_shards > 1:
             from repro.sharding import dmf as sharded_dmf
             prop = sharded_dmf.make_shard_plan(_as_neighbor_table(M), cfg)
-            epoch_fn = train_epoch
         else:
             prop = _as_neighbor_table(M)
-            epoch_fn = train_epoch
     collector = None
     if tele_on:
         from repro.obs import telemetry as tele_lib
@@ -1095,16 +1031,13 @@ def fit(
         t0 = time.perf_counter() if tele_on else 0.0
         dstats = None
         with trace_lib.span("fit.epoch", epoch=t):
-            if plan is not None:
-                out = train_epoch_churn(state, prop, train, cfg, rng, t,
-                                        plan, ring, accountant=accountant,
-                                        attack=attack_plan, byz=byz,
-                                        tele=tele_on)
-            elif epoch_fn is train_epoch_dense:
-                out = epoch_fn(state, prop, train, cfg, rng)
+            if dense_reference:
+                out = train_epoch_dense(state, prop, train, cfg, rng)
             else:
-                out = epoch_fn(state, prop, train, cfg, rng,
-                               accountant=accountant, tele=tele_on)
+                out = train_epoch(state, prop, train, cfg, rng,
+                                  accountant=accountant, tele=tele_on, t=t,
+                                  plan=plan, ring=ring, attack=attack_plan,
+                                  byz=byz)
         if tele_on:
             state, l, dstats = out
         else:

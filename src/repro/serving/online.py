@@ -8,7 +8,7 @@ Eqs. 9-11 local SGD step for (i, j) — plus a few sampled negatives, exactly
 the training-time objective — and ships only the global-factor gradient
 ∂L/∂p^i_j to its ≤D-hop `walk_neighbor_table` receivers. Ratings never
 leave the user; the privacy contract is unchanged from training (the same
-`core/dmf._sparse_batch_update` executes the step).
+`core/dmf._sparse_batch_update_messages` executes the step).
 
 Locality guarantee (unit-tested): one refresh touches
   * U rows:  only the users with new check-ins ("affected"),
@@ -19,7 +19,7 @@ and nothing else — the served population keeps its factors bit-identical.
 
 Events are padded to a fixed dispatch shape (``OnlineConfig.batch_cap``)
 so every refresh reuses one compiled step; padded rows carry conf=0 and
-valid=0 and contribute exactly nothing (see `_sparse_batch_update`). The
+valid=0 and contribute exactly nothing (see `dmf._step_deltas`). The
 U/P/Q buffers are donated to the step — refresh is in-place at the XLA
 level, no copy of the (I, J, K) factors per event batch.
 
@@ -61,10 +61,11 @@ class RefreshReport:
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(0, 1, 2))
 def _refresh_step(U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf, valid, rid,
                   dp_seed, cfg):
-    return dmf._sparse_batch_update(
+    U, P, Q, loss, _ = dmf._sparse_batch_update_messages(
         U, P, Q, nbr_idx, nbr_wgt, ui, vj, r, conf, cfg, valid=valid,
         rid=rid, dp_seed=dp_seed,
     )
+    return U, P, Q, loss
 
 
 def _event_batches(events: np.ndarray, cfg: dmf.DMFConfig, ocfg: OnlineConfig,

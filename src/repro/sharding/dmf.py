@@ -31,7 +31,7 @@ on its home shard (tests/test_dmf_sharded.py::test_privacy_*).
 Batch routing: the epoch's minibatch stream is the SAME stream the
 single-device path samples (same rng), with each minibatch's rows routed
 host-side to their user's home shard and padded to a fixed per-shard
-capacity with valid=0 rows (exact no-ops, the `_sparse_batch_update`
+capacity with valid=0 rows (exact no-ops, the `dmf._step_deltas`
 convention). SGD batch semantics are unchanged — a minibatch's updates are
 an order-free sum, so distributing its rows over shards is associativity,
 not approximation (DESIGN.md §8).
@@ -226,9 +226,17 @@ def build_outbox(gp, tbl_idx, tbl_wgt, vj):
     return out_w, out_i, out_g, out_v
 
 
+def _scatter_received(P, ri, rv, upd):
+    """`dmf._p_scatter_add` of the received (D, B, S) slots: local
+    receiver rows ``ri``, item ids ``rv`` (D, B), updates (D, B, S, K)."""
+    S, K = upd.shape[-2:]
+    return dmf_lib._p_scatter_add(P, ri.reshape(-1, S), rv.reshape(-1),
+                                  upd.reshape(-1, S, K))
+
+
 def _sharded_batch_update(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, noise,
                           cfg: dmf_lib.DMFConfig, prop_now=None,
-                          online_local=None, byz=None, amul=None, ashill=None,
+                          recv_gate=None, byz=None, amul=None, ashill=None,
                           dirs=None, vjm=None, bkt=None, byz_cap=0,
                           tele=False):
     """One minibatch of Alg. 1 on one shard: local gathers + Eq. 9-11 via
@@ -250,10 +258,10 @@ def _sharded_batch_update(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, noise,
     Fault gates (robustness/faults.py; both None on the fault-free path):
     ``prop_now`` (B,) restricts a straggler row's scatter to the sender's
     own self slot (dest shard == me AND local row == sender), pre-outbox —
-    its neighbor deliveries come from the delay ring later; ``online_local``
+    its neighbor deliveries come from the delay ring later; ``recv_gate``
     (rows,) zeroes received weights into this shard's offline rows.
-    Returns the released message block ``gp`` too (the churn epoch buffers
-    it); the fault-free epoch discards it.
+    Returns the released message block ``gp`` too (the epoch buffers it in
+    the delay ring, or discards it).
 
     Byzantine path (``byz`` a static `DefenseConfig`; None = untouched
     trace, see `dmf._sparse_batch_update_messages`): the sender's line-11
@@ -306,10 +314,10 @@ def _sharded_batch_update(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, noise,
         ri = jax.lax.all_to_all(out_i, AXIS, 0, 0)
         rg = jax.lax.all_to_all(out_g, AXIS, 0, 0)   # (D, B, K)
         rv = jax.lax.all_to_all(out_v, AXIS, 0, 0)   # (D, B)
-        if online_local is not None:
-            rw = rw * online_local[ri]               # offline receivers get 0
+        if recv_gate is not None:
+            rw = rw * recv_gate[ri]                  # offline receivers get 0
         upd = rw[..., None] * rg[:, :, None, :]      # (D, B, S, K)
-        P = P.at[ri, rv[:, :, None]].add(-theta * upd)
+        P = _scatter_received(P, ri, rv, -theta * upd)
         if tele:
             me = jax.lax.axis_index(AXIS)
             D = rw.shape[0]
@@ -333,8 +341,8 @@ def _sharded_batch_update(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, noise,
     selfm = ((jnp.arange(D)[None, :, None] == me)
              & (pi == ui[:, None, None])).astype(pw.dtype)
     w_self = jnp.sum(pw * selfm, axis=(1, 2))
-    if online_local is not None:
-        w_self = w_self * online_local[ui]
+    if recv_gate is not None:
+        w_self = w_self * recv_gate[ui]
     P = P.at[ui, vj].add(-theta * w_self[:, None] * gp)
     pw_msg = pw * (1.0 - selfm)
     if prop_now is not None:
@@ -348,24 +356,12 @@ def _sharded_batch_update(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, noise,
     ri = jax.lax.all_to_all(out_i, AXIS, 0, 0)
     rg = jax.lax.all_to_all(out_g, AXIS, 0, 0)       # (D, B, K)
     rv = jax.lax.all_to_all(out_v, AXIS, 0, 0)       # (D, B)
-    if online_local is not None:
-        rw = rw * online_local[ri]
+    if recv_gate is not None:
+        rw = rw * recv_gate[ri]
     rw_pre = rw   # pre-screen delivery weights (telemetry baseline)
-    if byz.screen:
-        ok = byz_lib.screen_ok(rg, byz.norm_cap)     # (D, B)
-        rg = jnp.where(ok[..., None] > 0, rg, 0.0)
-        rw = rw * ok[:, :, None]
-    # 0·NaN = NaN: zero-weight slots must deliver exactly 0 even when the
-    # (undefended) message content is a bomb. With screening on, rg is
-    # already zeroed wherever it was non-finite, so the plain multiply is
-    # safe — and ±0 contributions leave the scatter-add bitwise unchanged.
-    if byz.screen:
-        upd = rw[..., None] * rg[:, :, None, :]
-    else:
-        upd = jnp.where((rw > 0)[..., None],
-                        rw[..., None] * rg[:, :, None, :], 0.0)
+    rw, upd = dmf_lib._screened(rg, rw, byz)         # screened per receiver
     if byz.aggregation == "sum":
-        P = P.at[ri, rv[:, :, None]].add(-theta * upd)
+        P = _scatter_received(P, ri, rv, -theta * upd)
         scat = upd
     else:
         b_id, b_pos, b_recv, b_item = bkt
@@ -388,9 +384,11 @@ def _sharded_batch_update(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, noise,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cfg", "mesh", "tele"), donate_argnums=(0, 1, 2))
+    jax.jit, static_argnames=("cfg", "mesh", "tele", "byz", "byz_cap"),
+    donate_argnums=(0, 1, 2))
 def _epoch_sharded(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, rid, dp_seed,
-                   cfg, mesh, tele: bool = False):
+                   cfg, mesh, tele: bool = False, faults=None, byz=None,
+                   byz_cap: int = 0):
     """A full epoch as ONE SPMD dispatch: shard_map over the learner axis,
     `lax.scan` over minibatches inside. Inputs: U (I_pad, K), P/Q
     (I_pad, J, K), tables (I_pad, D, S), batches (nb, D, Bs), plus the
@@ -401,121 +399,43 @@ def _epoch_sharded(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, rid, dp_seed,
     (n, K), small next to P) and gathers its routed rows' slices by rid:
     bit-identical noise to the single-device scan for every row, any mesh
     width. Returns the updated factors and per-(batch, shard) losses
-    (nb, D)."""
+    (nb, D).
+
+    Faults (``faults``, a `dmf.EpochFaults` laid out for the mesh; None
+    traces none of this): the routed sender-online mask multiplies
+    ``valid``; the other gates, attack rows and buckets reach every
+    minibatch step as in `dmf._epoch_scan`. The scan's first step delivers
+    the replicated ring's due messages, which needs every sender's receiver
+    list destined for THIS shard's rows: the partitioned table sharded by
+    DESTINATION, which the body takes from ``pidx``/``pwgt`` a second time
+    under spec P(None, learners). The epoch's released messages are
+    re-assembled into a replicated (n, K) stream block for the ring: each
+    shard scatters its routed rows' gp by global stream id, then one `psum`
+    (padded rows carry gp=0/rid=0 — they add zero); it follows the losses.
+    Under the trivial schedule every fault op multiplies by 1.0 — the
+    outputs are bitwise the fault-free program's."""
     from repro.privacy import mechanism
     noise_on = cfg.dp and cfg.mode != "ldmf" and mechanism.noise_std(cfg) > 0
+    has_ring = faults is not None and faults.ring is not None
 
-    def shard_body(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, rid, dp_seed):
+    def shard_body(U, P, Q, pidx, pwgt, dtbl, ui, vj, r, conf, valid, rid,
+                   dp_seed, faults):
         ui, vj, r, conf, valid, rid = (
             x[:, 0] for x in (ui, vj, r, conf, valid, rid))
-        if noise_on:
-            from repro.kernels.dp_noise import gauss_counter
-            nb = ui.shape[0]
-            K = U.shape[-1]
-            all_rid = jnp.arange(
-                nb * cfg.batch_size, dtype=jnp.int32).reshape(-1, 1)
-            Z = mechanism.noise_std(cfg) * gauss_counter(dp_seed, all_rid, K)
-
-        def body(carry, batch):
-            U, P, Q = carry
-            b_ui, b_vj, b_r, b_conf, b_val, b_rid = batch
-            out = _sharded_batch_update(
-                U, P, Q, pidx, pwgt, b_ui, b_vj, b_r, b_conf, b_val,
-                Z[b_rid] if noise_on else None, cfg, tele=tele)
-            if tele:
-                U, P, Q, loss, _, tvec = out
-                return (U, P, Q), (loss, tvec)
-            U, P, Q, loss, _ = out
-            return (U, P, Q), loss
-
-        (U, P, Q), ys = jax.lax.scan(
-            body, (U, P, Q), (ui, vj, r, conf, valid, rid))
-        if tele:
-            losses, tvecs = ys
-            # (1, TELE_W) per shard -> (D, TELE_W) at the out spec
-            return U, P, Q, losses[:, None], tvecs.sum(axis=0)[None]
-        return U, P, Q, ys[:, None]
-
-    out_specs = (P_(AXIS), P_(AXIS), P_(AXIS), P_(None, AXIS))
-    if tele:
-        out_specs += (P_(AXIS),)
-    return jax.shard_map(
-        shard_body, mesh=mesh,
-        in_specs=(P_(AXIS), P_(AXIS), P_(AXIS), P_(AXIS), P_(AXIS),
-                  P_(None, AXIS), P_(None, AXIS), P_(None, AXIS),
-                  P_(None, AXIS), P_(None, AXIS), P_(None, AXIS), P_()),
-        out_specs=out_specs,
-        check_vma=False,
-    )(U, P, Q, pidx, pwgt, ui, vj, r, conf, valid, rid, dp_seed)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("cfg", "mesh", "use_ring", "byz", "use_attack",
-                     "byz_cap", "tele"),
-    donate_argnums=(0, 1, 2))
-def _epoch_sharded_churn(U, P, Q, pidx, pwgt, dpidx, dpwgt, ui, vj, r, conf,
-                         valid, rid, prop_now, online, ring_gp, ring_ui,
-                         ring_vj, ring_deliver, dp_seed, amul, ashill, vjm,
-                         dirs, b_id, b_pos, b_recv, b_item, cfg, mesh,
-                         use_ring, byz=None, use_attack=False, byz_cap=0,
-                         tele: bool = False):
-    """`_epoch_sharded` under a fault schedule — STILL one SPMD dispatch.
-
-    Extra inputs: the fault gates (``prop_now`` routed like the batches,
-    ``online`` (I_pad,) row-sharded), the SAME partitioned table a second
-    time sharded by DESTINATION (``dpidx``/``dpwgt`` with spec
-    P(None, learners) → each shard holds every sender's receiver-list
-    destined for ITS rows — what stale-message delivery needs, no comms),
-    and the replicated delay-ring content. Start-of-epoch delivery scatters
-    each due buffered message into the local P rows (neighbor slots only,
-    receiver-online gated). The epoch's released messages are re-assembled
-    into a replicated (n, K) stream block for the ring: each shard scatters
-    its routed rows' gp by global stream id, then one `psum` (padded rows
-    carry gp=0/rid=0 — they add zero). Returns (U, P, Q, losses, block).
-
-    Under the trivial schedule (gates all ones, ``use_ring=False``) every
-    fault op multiplies by 1.0 — the outputs are bitwise `_epoch_sharded`'s.
-
-    Byzantine args (``byz``/``use_attack``/``byz_cap`` static; attack
-    arrays routed like the batches, ``dirs`` row-sharded, bucket arrays in
-    per-destination received-slot order with spec P(None, learners)):
-    with ``byz=None`` every one is a statically dead input and the trace
-    is unchanged. Ring messages are screened AT DELIVERY on the receiving
-    shard — stale corrupted messages don't dodge the gate."""
-    from repro.privacy import mechanism
-    noise_on = cfg.dp and cfg.mode != "ldmf" and mechanism.noise_std(cfg) > 0
-    theta = cfg.lr
-    robust = byz is not None and byz.aggregation != "sum"
-
-    def shard_body(U, P, Q, pidx, pwgt, dpidx, dpwgt, ui, vj, r, conf, valid,
-                   rid, prop_now, online, ring_gp, ring_ui, ring_vj,
-                   ring_deliver, dp_seed, amul, ashill, vjm, dirs, b_id,
-                   b_pos, b_recv, b_item):
-        ui, vj, r, conf, valid, rid, prop_now = (
-            x[:, 0] for x in (ui, vj, r, conf, valid, rid, prop_now))
-        rows = U.shape[0]
         K = U.shape[-1]
-        me = jax.lax.axis_index(AXIS)
-        if use_ring:
-            # deliver the buffered messages due THIS epoch into local P rows
-            gflat = ring_gp.reshape(-1, K)               # (L·n, K)
-            di = dpidx[ring_ui, 0]                       # (L·n, S) local rows
-            dw = dpwgt[ring_ui, 0]
-            selfm = ((me * rows + di) == ring_ui[:, None]).astype(dw.dtype)
-            dw = (dw * (1.0 - selfm) * online[di]
-                  * ring_deliver[:, None])
-            if byz is not None:
-                from repro.robustness import byzantine as byz_lib
-                if byz.screen:
-                    okd = byz_lib.screen_ok(gflat, byz.norm_cap)
-                    gflat = jnp.where(okd[:, None] > 0, gflat, 0.0)
-                    dw = dw * okd[:, None]
-                dupd = jnp.where((dw > 0)[:, :, None],
-                                 dw[:, :, None] * gflat[:, None, :], 0.0)
-            else:
-                dupd = dw[:, :, None] * gflat[:, None, :]
-            P = P.at[di, ring_vj[:, None]].add(-theta * dupd)
+        if faults is not None:
+            valid = valid * faults.valid[:, 0]
+        first = None
+        if has_ring:
+            ring_ui = faults.ring[1]
+            recv = dtbl[0][ring_ui, 0]                   # (L·n, S) local rows
+            me = jax.lax.axis_index(AXIS)
+            deliver = functools.partial(
+                dmf_lib._deliver_ring, ring=faults.ring, recv=recv,
+                wgt=dtbl[1][ring_ui, 0],
+                is_self=(me * U.shape[0] + recv) == ring_ui[:, None],
+                recv_gate=faults.recv_gate, byz=byz, theta=cfg.lr)
+            first = jnp.arange(ui.shape[0]) == 0
         if noise_on:
             from repro.kernels.dp_noise import gauss_counter
             nb = ui.shape[0]
@@ -523,201 +443,53 @@ def _epoch_sharded_churn(U, P, Q, pidx, pwgt, dpidx, dpwgt, ui, vj, r, conf,
                 nb * cfg.batch_size, dtype=jnp.int32).reshape(-1, 1)
             Z = mechanism.noise_std(cfg) * gauss_counter(dp_seed, all_rid, K)
 
-        xs = [ui, vj, r, conf, valid, rid, prop_now]
-        if use_attack:
-            xs += [amul[:, 0], ashill[:, 0]]
-        if byz is not None:
-            xs.append(vjm[:, 0])
-        if robust:
-            xs += [b_id[:, 0], b_pos[:, 0], b_recv[:, 0], b_item[:, 0]]
-
         def body(carry, batch):
             U, P, Q = carry
-            b_ui, b_vj, b_r, b_conf, b_val, b_rid, b_prop = batch[:7]
-            i = 7
-            b_amul = b_ashill = b_vjm = bkt = None
-            if use_attack:
-                b_amul, b_ashill = batch[i], batch[i + 1]
-                i += 2
-            if byz is not None:
-                b_vjm = batch[i]
-                i += 1
-            if robust:
-                bkt = batch[i:i + 4]
+            b_ui, b_vj, b_r, b_conf, b_val, b_rid, rows, b_first = batch
+            if has_ring:
+                P = deliver(P, b_first)
             out = _sharded_batch_update(
                 U, P, Q, pidx, pwgt, b_ui, b_vj, b_r, b_conf, b_val,
-                Z[b_rid] if noise_on else None, cfg,
-                prop_now=b_prop, online_local=online, byz=byz,
-                amul=b_amul, ashill=b_ashill,
-                dirs=dirs if use_attack else None, vjm=b_vjm, bkt=bkt,
-                byz_cap=byz_cap, tele=tele)
-            if tele:
-                U, P, Q, loss, gp, tvec = out
-            else:
-                U, P, Q, loss, gp = out
-            y = [loss]
-            if use_ring:
-                y.append(gp)
-            if tele:
-                y.append(tvec)
-            return (U, P, Q), (tuple(y) if len(y) > 1 else y[0])
+                Z[b_rid] if noise_on else None, cfg, tele=tele,
+                **dmf_lib._step_faults(rows, faults, byz, byz_cap))
+            return out[:3], (out[3], out[4] if has_ring else None,
+                             out[5] if tele else None)
 
-        (U, P, Q), ys = jax.lax.scan(body, (U, P, Q), tuple(xs))
-        tvecs = None
-        if tele:
-            ys, tvecs = (ys[:-1], ys[-1])
-            ys = ys if use_ring else ys[0]
-        if use_ring:
-            losses, gps = ys
+        rows = dmf_lib._fault_rows(faults, lambda a: a[:, 0])
+        (U, P, Q), (losses, gps, tvecs) = jax.lax.scan(
+            body, (U, P, Q), (ui, vj, r, conf, valid, rid, rows, first))
+        ret = (U, P, Q, losses[:, None])
+        if has_ring:
             # replicated released-message stream block for the delay ring:
             # scatter-add my rows by global stream id, psum across shards
-            n_stream = ui.shape[0] * cfg.batch_size
-            blk = jnp.zeros((n_stream, K), gps.dtype)
+            blk = jnp.zeros((ui.shape[0] * cfg.batch_size, K), gps.dtype)
             blk = blk.at[rid.reshape(-1)].add(gps.reshape(-1, K))
-            blk = jax.lax.psum(blk, AXIS)
-        else:
-            losses = ys
-            blk = jnp.zeros((1, K), jnp.float32)
-        ret = (U, P, Q, losses[:, None], blk)
+            ret += (jax.lax.psum(blk, AXIS),)
         if tele:
             # (1, TELE_W) per shard -> (D, TELE_W) at the out spec
             ret += (tvecs.sum(axis=0)[None],)
         return ret
 
-    out_specs = (P_(AXIS), P_(AXIS), P_(AXIS), P_(None, AXIS), P_())
+    row, batch = P_(AXIS), P_(None, AXIS)
+    fspec = None
+    if faults is not None:
+        fspec = dmf_lib.EpochFaults(
+            batch, batch, row, None if faults.ring is None else P_(),
+            None if faults.attack is None else (batch,) * 3 + (row,),
+            None if faults.buckets is None else batch)
+    out_specs = (row, row, row, batch)
+    if has_ring:
+        out_specs += (P_(),)
     if tele:
-        out_specs += (P_(AXIS),)
+        out_specs += (row,)
     return jax.shard_map(
         shard_body, mesh=mesh,
-        in_specs=(P_(AXIS), P_(AXIS), P_(AXIS), P_(AXIS), P_(AXIS),
-                  P_(None, AXIS), P_(None, AXIS),
-                  P_(None, AXIS), P_(None, AXIS), P_(None, AXIS),
-                  P_(None, AXIS), P_(None, AXIS), P_(None, AXIS),
-                  P_(None, AXIS), P_(AXIS),
-                  P_(), P_(), P_(), P_(), P_(),
-                  P_(None, AXIS), P_(None, AXIS), P_(None, AXIS), P_(AXIS),
-                  P_(None, AXIS), P_(None, AXIS), P_(None, AXIS),
-                  P_(None, AXIS)),
+        in_specs=(row,) * 5 + ((batch, batch) if has_ring else None,)
+        + (batch,) * 6 + (P_(), fspec),
         out_specs=out_specs,
         check_vma=False,
-    )(U, P, Q, pidx, pwgt, dpidx, dpwgt, ui, vj, r, conf, valid, rid,
-      prop_now, online, ring_gp, ring_ui, ring_vj, ring_deliver, dp_seed,
-      amul, ashill, vjm, dirs, b_id, b_pos, b_recv, b_item)
-
-
-def train_epoch_churn_sharded(
-    state: dmf_lib.DMFState,
-    prop,
-    train: np.ndarray,
-    cfg: dmf_lib.DMFConfig,
-    rng: np.random.Generator,
-    t: int,
-    schedule,                   # robustness.faults.ChurnPlan
-    ring,                       # robustness.faults.DelayRing | None
-    accountant=None,
-    attack=None,                # robustness.byzantine.AttackPlan | None
-    byz=None,                   # robustness.byzantine.DefenseConfig | None
-    tele: bool = False,         # append the (n_shards, TELE_W) device stats
-) -> tuple[dmf_lib.DMFState, float]:
-    """Sharded counterpart of `dmf.train_epoch_churn`: the same sampled
-    stream and fault gates (host-side, shard-count-independent), rows and
-    gates routed to home shards, one SPMD dispatch per epoch. The delay
-    ring is replicated — its written content is the psum-assembled global
-    released-message stream, so a run's ring state is invariant to the
-    mesh width (and a resume can switch shard counts).
-
-    ``attack``/``byz`` mirror the single-device path: the attack arrays
-    are realized on the ROUTED stream (same per-(user, epoch) corruption,
-    whatever shard a row landed on), message-bucket membership is compiled
-    per destination shard in received-slot order, and screening decisions
-    depend only on message content + τ — all shard-count invariant
-    (tests/test_byzantine.py pins defended runs across mesh widths)."""
-    plan = _as_plan(prop, cfg)
-    ui, vj, r, conf = dmf_lib.sample_epoch(train, cfg, rng)
-    B = cfg.batch_size
-    nb = len(ui) // B
-    n = nb * B
-    shape = (nb, B)
-    ui2 = ui[:n].reshape(shape)
-    vj2 = vj[:n].reshape(shape)
-    _, dp_seed = dmf_lib.epoch_dp_inputs(cfg, rng, n)
-    on, sender_on, prop_now, due = schedule.epoch_row_masks(t, ui2)
-    conf2 = conf[:n].reshape(shape) * sender_on
-    if accountant is not None:
-        accountant.observe_epoch(ui2, valid=sender_on)
-    ui_l, vj_s, r_s, conf_s, valid, rid, son_s, pnow_s = shard_batches(
-        ui2, vj2, r[:n].reshape(shape), conf2, cfg.n_shards, plan.rows,
-        extras=(sender_on, prop_now))
-    valid = valid * son_s       # offline senders' routed rows are inert
-    online_pad = np.zeros(plan.n_rows_padded, np.float32)
-    online_pad[: schedule.n_users] = on
-    use_ring = ring is not None
-    if use_ring:
-        r_ui = ring.ui.reshape(-1)
-        r_vj = ring.vj.reshape(-1)
-        r_del = (ring.due.reshape(-1) == t).astype(np.float32)
-        ring_gp = ring.gp
-    else:  # statically-skipped dummies (dead jit inputs)
-        r_ui = np.zeros(1, np.int32)
-        r_vj = np.zeros(1, np.int32)
-        r_del = np.zeros(1, np.float32)
-        ring_gp = jnp.zeros((1, 1, cfg.dim), jnp.float32)
-    use_attack = attack is not None
-    K = cfg.dim
-    if use_attack:
-        assert byz is not None
-        # realize the attack on the routed stream by GLOBAL user id —
-        # identical per-(user, epoch) corruption at every mesh width;
-        # padded slots are forced honest via the routed validity
-        gl_ui = (np.arange(cfg.n_shards)[None, :, None] * plan.rows
-                 + ui_l).astype(np.int64)
-        amul, ashill, vjm = attack.epoch_row_attack(
-            t, gl_ui, vj_s, sender_on=(valid > 0))
-        # the ring buffers the UNSHARDED stream: same realization there
-        amul_g, ashill_g, vjm_g = attack.epoch_row_attack(
-            t, ui2, vj2, sender_on=sender_on)
-        dirs_pad = np.zeros((plan.n_rows_padded, K), np.float32)
-        dirs_pad[: schedule.n_users] = attack.dirs
-        dirs = jnp.asarray(dirs_pad)
-    else:
-        amul = ashill = np.zeros((1, cfg.n_shards, 1), np.float32)
-        vjm = vj_s
-        vjm_g = vj2
-        dirs = jnp.zeros((cfg.n_shards, K), jnp.float32)
-    robust = byz is not None and byz.aggregation != "sum"
-    if robust:
-        from repro.robustness import byzantine as byz_lib
-        groups = byz_lib.group_messages_sharded(
-            ui_l, vjm, valid, plan.part.idx, plan.part.wgt, plan.rows,
-            cfg.n_shards, cfg.n_items, prop_now=pnow_s, online=online_pad)
-        gb = (jnp.asarray(groups.bucket_id), jnp.asarray(groups.pos),
-              jnp.asarray(groups.recv), jnp.asarray(groups.item))
-        byz_cap = groups.cap
-    else:
-        z3 = np.zeros((1, cfg.n_shards, 1), np.int32)
-        gb = (z3, z3, z3, z3)
-        byz_cap = 0
-    st = shard_state(state, plan)
-    out = _epoch_sharded_churn(
-        st.U, st.P, st.Q, plan.part.idx, plan.part.wgt,
-        plan.part.idx, plan.part.wgt,
-        jnp.asarray(ui_l), jnp.asarray(vj_s), jnp.asarray(r_s),
-        jnp.asarray(conf_s), jnp.asarray(valid), jnp.asarray(rid),
-        jnp.asarray(pnow_s), jnp.asarray(online_pad),
-        ring_gp, jnp.asarray(r_ui), jnp.asarray(r_vj), jnp.asarray(r_del),
-        jnp.asarray(dp_seed, jnp.int32),
-        jnp.asarray(amul), jnp.asarray(ashill), jnp.asarray(vjm), dirs,
-        gb[0], gb[1], gb[2], gb[3],
-        cfg, plan.mesh, use_ring, byz, use_attack, byz_cap, tele=tele)
-    U, Pm, Q, losses, blk = out[:5]
-    if use_ring:
-        ring.write(t, blk, ui2, vjm_g if byz is not None else vj2, due)
-    total = float(np.asarray(losses, dtype=np.float64).sum())
-    realized = int(sender_on.sum())
-    l = total / max(realized, 1)
-    if tele:
-        return dmf_lib.DMFState(U, Pm, Q), l, np.asarray(out[5])
-    return dmf_lib.DMFState(U, Pm, Q), l
+    )(U, P, Q, pidx, pwgt, (pidx, pwgt) if has_ring else None, ui, vj, r,
+      conf, valid, rid, dp_seed, faults)
 
 
 def _as_plan(prop, cfg: dmf_lib.DMFConfig) -> ShardPlan:
@@ -749,6 +521,12 @@ def train_epoch_sharded(
     rng: np.random.Generator,
     accountant=None,
     tele: bool = False,         # append the (n_shards, TELE_W) device stats
+    *,
+    t: int = 0,
+    plan=None,                  # robustness.faults.ChurnPlan | None
+    ring=None,                  # robustness.faults.DelayRing | None
+    attack=None,                # robustness.byzantine.AttackPlan | None
+    byz=None,                   # robustness.byzantine.DefenseConfig | None
 ) -> tuple[dmf_lib.DMFState, float]:
     """Sharded counterpart of `dmf.train_epoch`: identical minibatch stream
     (same rng consumption — the per-epoch DP seed draw included, so DP-on
@@ -757,31 +535,61 @@ def train_epoch_sharded(
     padded+sharded across epochs (donated buffers, no per-epoch host
     round-trip); slice with `unpad_state` when done — `dmf.fit` does both
     automatically. ``accountant`` observes the realized stream like the
-    single-device path (ε accounting is shard-count-independent)."""
-    plan = _as_plan(prop, cfg)
-    ui, vj, r, conf = dmf_lib.sample_epoch(train, cfg, rng)
-    B = cfg.batch_size
-    nb = len(ui) // B
-    n = nb * B
-    shape = (nb, B)
-    _, dp_seed = dmf_lib.epoch_dp_inputs(cfg, rng, n)
-    if accountant is not None:
-        accountant.observe_epoch(ui[:n].reshape(shape))
-    ui_l, vj_s, r_s, conf_s, valid, rid = shard_batches(
-        ui[:n].reshape(shape), vj[:n].reshape(shape),
-        r[:n].reshape(shape), conf[:n].reshape(shape),
-        cfg.n_shards, plan.rows)
-    st = shard_state(state, plan)
+    single-device path (ε accounting is shard-count-independent).
+
+    The fault inputs are the single-device path's, built host-side on the
+    same stream and routed to home shards with it. The delay ring is
+    replicated — its written content is the psum-assembled global
+    released-message stream, so a run's ring state is invariant to the mesh
+    width (and a resume can switch shard counts). The attack arrays are
+    realized on the ROUTED stream (same per-(user, epoch) corruption,
+    whatever shard a row landed on), message-bucket membership is compiled
+    per destination shard in received-slot order, and screening decisions
+    depend only on message content + τ — all shard-count invariant
+    (tests/test_byzantine.py pins defended runs across mesh widths)."""
+    shp = _as_plan(prop, cfg)
+    ep = dmf_lib._epoch_inputs(train, cfg, rng, accountant, t, plan, ring,
+                               attack)
+    ui_l, vj_s, r_s, conf_s, valid, rid, *gates = shard_batches(
+        ep.ui, ep.vj, ep.r, ep.conf, cfg.n_shards, shp.rows,
+        extras=() if plan is None else ep.masks[1:3])
+    faults, byz_cap = None, 0
+    if plan is not None:
+        son_s, pnow_s = gates
+        live = valid * son_s    # routed rows that exist, sender online
+        online = np.zeros(shp.n_rows_padded, np.float32)
+        online[: plan.n_users] = ep.masks[0]
+        vjm, atk, bkt = vj_s, None, None
+        if attack is not None:
+            # realize the attack on the routed stream by GLOBAL user id —
+            # identical per-(user, epoch) corruption at every mesh width;
+            # padded slots are forced honest via the routed validity
+            gl_ui = (np.arange(cfg.n_shards)[None, :, None] * shp.rows
+                     + ui_l).astype(np.int64)
+            amul, ashill, vjm = attack.epoch_row_attack(
+                t, gl_ui, vj_s, sender_on=live > 0)
+            dirs = np.zeros((shp.n_rows_padded, cfg.dim), np.float32)
+            dirs[: plan.n_users] = attack.dirs
+            atk = (amul, ashill, vjm, dirs)
+        if byz is not None and byz.aggregation != "sum":
+            from repro.robustness import byzantine as byz_lib
+            groups = byz_lib.group_messages_sharded(
+                ui_l, vjm, live, shp.part.idx, shp.part.wgt, shp.rows,
+                cfg.n_shards, cfg.n_items, prop_now=pnow_s, online=online)
+            bkt = (groups.bucket_id, groups.pos, groups.recv, groups.item)
+            byz_cap = groups.cap
+        faults = jax.tree.map(jnp.asarray, dmf_lib.EpochFaults(
+            son_s, pnow_s, online, ep.ring, atk, bkt))
+    st = shard_state(state, shp)
     out = _epoch_sharded(
-        st.U, st.P, st.Q, plan.part.idx, plan.part.wgt,
-        jnp.asarray(ui_l), jnp.asarray(vj_s), jnp.asarray(r_s),
-        jnp.asarray(conf_s), jnp.asarray(valid), jnp.asarray(rid),
-        jnp.asarray(dp_seed, jnp.int32), cfg, plan.mesh, tele=tele)
+        st.U, st.P, st.Q, shp.part.idx, shp.part.wgt,
+        *(jnp.asarray(a) for a in (ui_l, vj_s, r_s, conf_s, valid, rid)),
+        jnp.asarray(ep.dp_seed, jnp.int32), cfg, shp.mesh, tele=tele,
+        faults=faults, byz=byz, byz_cap=byz_cap)
     U, Pm, Q, losses = out[:4]
-    total = float(np.asarray(losses, dtype=np.float64).sum())
-    l = total / max(n, 1)
+    l = ep.finish(t, losses, out[4] if ring is not None else None, ring)
     if tele:
-        return dmf_lib.DMFState(U, Pm, Q), l, np.asarray(out[4])
+        return dmf_lib.DMFState(U, Pm, Q), l, np.asarray(out[-1])
     return dmf_lib.DMFState(U, Pm, Q), l
 
 

@@ -61,13 +61,26 @@ def test_scan_sparse_epoch_matches_dense_reference(mode):
                                atol=1e-5)
 
 
-def _scatter_case(repeats):
+def _scatter_case(case):
     """(P, recv, items, upd) for a small (I, J, K): walk-weighted messages,
     the last slot of every row a zero-weight self pad. With ``repeats``
     the same (receiver, item) pair is hit twice within row 0, and again
-    across rows (rows 0 and 3 share item 2, rows 1 and 2 item 4); without,
-    every live pair is distinct."""
+    across rows (rows 0 and 3 share item 2, rows 1 and 2 item 4); with
+    ``distinct`` every live pair is distinct. ``blocks``: 1,100 rows of
+    distinct pairs, more than two of `_p_scatter_add`'s row blocks, the
+    last one short."""
     rng = np.random.default_rng(11)
+    if case == "blocks":
+        I, J, K, S, N = 300, 8, 3, 2, 1100
+        assert N > 2 * dmf._SCATTER_ROWS and N % dmf._SCATTER_ROWS
+        rows = np.arange(N)
+        items = (rows % J).astype(np.int32)
+        recv = ((rows // J)[:, None] * S + np.arange(S)).astype(np.int32)
+        upd = rng.normal(size=(N, S, K)).astype(np.float32)
+        P = rng.normal(size=(I, J, K)).astype(np.float32)
+        return (jnp.asarray(P), jnp.asarray(recv), jnp.asarray(items),
+                jnp.asarray(upd))
+    repeats = case == "repeats"
     I, J, K = 9, 6, 3
     senders = np.asarray([0, 1, 2, 3], np.int32)
     if repeats:
@@ -88,9 +101,9 @@ def _scatter_case(repeats):
             jnp.asarray(upd))
 
 
-@pytest.mark.parametrize("repeats", [True, False], ids=["repeats", "distinct"])
-def test_p_scatter_add_matches_single_scatter(repeats):
-    P, recv, items, upd = _scatter_case(repeats)
+@pytest.mark.parametrize("case", ["repeats", "distinct", "blocks"])
+def test_p_scatter_add_matches_single_scatter(case):
+    P, recv, items, upd = _scatter_case(case)
     got = np.asarray(jax.jit(dmf._p_scatter_add)(P, recv, items, upd))
     want = np.asarray(jax.jit(
         lambda P, n, v, u: P.at[n, v[:, None]].add(u))(P, recv, items, upd))
@@ -101,7 +114,7 @@ def test_p_scatter_add_matches_single_scatter(repeats):
     touched[tuple(np.asarray(sorted(pairs)).T)] = True
     # rows and items nobody sends to, and the zero-weight pads, stay exact
     np.testing.assert_array_equal(got[~touched], np.asarray(P)[~touched])
-    if repeats:
+    if case == "repeats":
         np.testing.assert_allclose(got, want, rtol=1e-6)
     else:
         np.testing.assert_array_equal(got, want)

@@ -143,8 +143,8 @@ def test_offline_learner_rows_bit_frozen():
     rng = np.random.default_rng(cfg.seed)
     state0 = dmf.init_state(cfg, rng)
     before = {k: np.asarray(getattr(state0, k)).copy() for k in ("U", "P", "Q")}
-    state1, loss = dmf.train_epoch_churn(state0, nbr, ds.train, cfg, rng,
-                                         0, plan, None)
+    state1, loss = dmf.train_epoch(state0, nbr, ds.train, cfg, rng,
+                                   t=0, plan=plan, ring=None)
     assert np.isfinite(loss)
     for name in ("U", "P", "Q"):
         after = np.asarray(getattr(state1, name))
@@ -154,8 +154,8 @@ def test_offline_learner_rows_bit_frozen():
     U1 = np.asarray(state1.U).copy()   # the next epoch donates state1's buffers
     assert (U1 != before["U"]).any()
     # rejoin: epoch 1 (everyone online) moves the previously-frozen rows
-    state2, _ = dmf.train_epoch_churn(state1, nbr, ds.train, cfg, rng,
-                                      1, plan, None)
+    state2, _ = dmf.train_epoch(state1, nbr, ds.train, cfg, rng,
+                                t=1, plan=plan, ring=None)
     moved = np.asarray([
         (np.asarray(state2.U)[u] != U1[u]).any() for u in offline])
     assert moved.any(), "rejoined learners never caught back up"
@@ -177,8 +177,8 @@ def test_late_joiner_stateless_until_join_epoch():
             np.testing.assert_array_equal(np.asarray(state.U)[u], init["U"][u])
             np.testing.assert_array_equal(np.asarray(state.Q)[u], init["Q"][u])
             np.testing.assert_array_equal(np.asarray(state.P)[u], init["P"][u])
-        state, _ = dmf.train_epoch_churn(state, nbr, ds.train, cfg, rng,
-                                         t, plan, None)
+        state, _ = dmf.train_epoch(state, nbr, ds.train, cfg, rng,
+                                   t=t, plan=plan, ring=None)
 
 
 # ---------------------------------------------------------------------------
@@ -209,8 +209,8 @@ def _run_epochs(cfg, nbr, train, plan, epochs):
     ring = DelayRing.create(plan.k_max, nb * cfg.batch_size, cfg.dim)
     hist = [np.asarray(state.P).copy()]
     for t in range(epochs):
-        state, _ = dmf.train_epoch_churn(state, nbr, train, cfg, rng, t,
-                                         plan, ring)
+        state, _ = dmf.train_epoch(state, nbr, train, cfg, rng, t=t,
+                                   plan=plan, ring=ring)
         hist.append(np.asarray(state.P).copy())
     return hist
 

@@ -12,9 +12,11 @@ cases say nothing about results or speed.
 Two more cases compile the whole training epoch (`dmf._epoch_scan`) and
 the serve dispatch (`engine._dispatch_rows`) at Table 1 scale with and
 without their `jax.named_scope` names: the scopes are op metadata only, so
-the optimized programs must match once that metadata is stripped. One more
-reads the compiled epoch itself: the walk's P scatter must stay in place,
-in the layout the scan carries, with no per-step relayout of the whole P.
+the optimized programs must match once that metadata is stripped. More
+read the compiled epoch itself, plain, under a delay ring, with the
+defended `sum` exchange and learner-sharded over the four chips: the walk's
+P scatter must stay in place, in the layout the scan carries, with no
+per-step relayout of the whole P.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -26,19 +28,27 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
 from repro.core import dmf
 from repro.kernels import ops
+from repro.robustness.byzantine import DefenseConfig
 from repro.serving import engine
+from repro.sharding import dmf as sharded_dmf
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -112,16 +122,45 @@ def test_kernel_compiles_for_v5e(one_chip, name):
         f"{name}: no Mosaic kernel in the compiled program")
 
 
-def _epoch_scan(one_chip):
+CFG = dmf.DMFConfig(n_users=I, n_items=J, dim=K, beta=0.1, gamma=0.01,
+                    batch_size=B)
+NB, S = 110, 21                      # scan steps and walk fan-out, Table 1
+
+
+def _epoch_scan(one_chip, ring=False, byz=None):
+    """The single-device epoch; ``ring`` adds a trivial plan's gates and a
+    one-slot delay ring, ``byz`` the gates and the defended exchange."""
     def sds(shape, dtype=F32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    cfg = dmf.DMFConfig(n_users=I, n_items=J, dim=K, beta=0.1, gamma=0.01,
-                        batch_size=B)
-    nb, S = 110, 21                   # scan steps and walk fan-out, Table 1
-    return dmf._epoch_scan.lower(
-        sds((I, K)), sds((I, J, K)), sds((I, J, K)), sds((I, S), I32),
-        sds((I, S)), sds((nb, B), I32), sds((nb, B), I32), sds((nb, B)),
-        sds((nb, B)), sds((), I32), cfg)
+    args = (sds((I, K)), sds((I, J, K)), sds((I, J, K)), sds((I, S), I32),
+            sds((I, S)), sds((NB, B), I32), sds((NB, B), I32), sds((NB, B)),
+            sds((NB, B)), sds((), I32), CFG)
+    if not ring and byz is None:
+        return dmf._epoch_scan.lower(*args)
+    n = NB * B
+    faults = dmf.EpochFaults(
+        sds((NB, B)), sds((NB, B)), sds((I,)),
+        ring=(sds((1, n, K)), sds((n,), I32), sds((n,), I32), sds((n,)))
+        if ring else None)
+    return dmf._epoch_scan.lower(*args, faults=faults, byz=byz)
+
+
+def _epoch_sharded(topo):
+    """The learner-sharded epoch over the four described chips."""
+    mesh = Mesh(np.asarray(topo.devices), (sharded_dmf.AXIS,))
+    D, Bs = len(topo.devices), 96     # shards; routed rows per (batch, shard)
+
+    def sds(shape, dtype=F32, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, PartitionSpec(*spec)))
+    rows, batch = sharded_dmf.AXIS, (None, sharded_dmf.AXIS)
+    return sharded_dmf._epoch_sharded.lower(
+        sds((I, K), F32, rows), sds((I, J, K), F32, rows),
+        sds((I, J, K), F32, rows), sds((I, D, S), I32, rows),
+        sds((I, D, S), F32, rows), sds((NB, D, Bs), I32, *batch),
+        sds((NB, D, Bs), I32, *batch), sds((NB, D, Bs), F32, *batch),
+        sds((NB, D, Bs), F32, *batch), sds((NB, D, Bs), F32, *batch),
+        sds((NB, D, Bs), I32, *batch), sds((), I32), CFG, mesh)
 
 
 def _dispatch_rows(one_chip):
@@ -196,19 +235,32 @@ def _reachable(comps: dict[str, str], root: str) -> set[str]:
     return seen
 
 
-def test_epoch_scan_scatters_p_in_place_for_v5e(one_chip):
+EPOCHS = {
+    "plain": lambda topo, one_chip: _epoch_scan(one_chip),
+    "churn_ring": lambda topo, one_chip: _epoch_scan(one_chip, ring=True),
+    "byz_sum": lambda topo, one_chip: _epoch_scan(
+        one_chip, byz=DefenseConfig(screen=True, aggregation="sum")),
+    "sharded4": lambda topo, one_chip: _epoch_sharded(topo),
+}
+
+
+@pytest.mark.parametrize("case", list(EPOCHS))
+def test_epoch_scan_scatters_p_in_place_for_v5e(topo, one_chip, case):
     """The P scatter compiles to in-place scatter-adds on P as the scan
     carries it: no op on a flattened I*J (or I*J*K) operand, no while loop
     but the scan, no copy of a whole (I, J, K) factor inside the scan body,
-    and less temp memory than the single-scatter form's 4,019,105,280 B."""
-    compiled = _epoch_scan(one_chip).compile()
+    and less temp memory than the single-scatter form's 4,019,105,280 B.
+    On the mesh each chip holds a quarter of the learner rows, so the
+    whole factor there is the local (I/4, J, K) block."""
+    compiled = EPOCHS[case](topo, one_chip).compile()
     hlo = compiled.as_text()
-    for flat in (I * J, I * J * K):
+    rows = I // len(topo.devices) if case == "sharded4" else I
+    for flat in {I * J, I * J * K, rows * J, rows * J * K}:
         assert not re.search(rf"\b{flat}\b", hlo), flat
     whiles = re.findall(r"\swhile\(.*?body=%([\w.\-]+)", hlo)
     assert len(whiles) == 1, whiles
     comps = _computations(hlo)
-    copy = re.compile(rf"f32\[{I},{J},{K}\]\{{[^}}]*\}} copy\(")
+    copy = re.compile(rf"f32\[{rows},{J},{K}\]\{{[^}}]*\}} copy\(")
     body = _reachable(comps, whiles[0])
     assert not [c for c in body if copy.search(comps[c])]
     assert compiled.memory_analysis().temp_size_in_bytes < 4_019_105_280
